@@ -83,10 +83,7 @@ class CompileTracker:
         self._armed = True
         if self._listener_installed:
             return
-        try:
-            from jax import monitoring
-        except Exception:  # pragma: no cover — ancient jax
-            return
+        from jax import monitoring
 
         def _on_event(event: str, duration: float, **kw) -> None:
             if self._armed and any(event.startswith(e) for e in _COMPILE_EVENTS):

@@ -433,8 +433,6 @@ def roofline_row(target: AuditTarget, traced, compiled) -> dict:
 
     st = static_cost(traced.jaxpr)
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):        # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     hc = hlo_analyze(compiled.as_text())
 
     def ratio(a: float, b: float) -> float:

@@ -8,8 +8,8 @@ its own slice of the device fleet. The invariants this module enforces:
   compiled executable) is shared across all replicas — a program migrated
   between replicas by the MORI balancer lands on byte-identical layouts;
 * replica device groups are disjoint slices of the fleet when enough
-  devices exist, and alias the host device(s) otherwise (the CPU test
-  path, where N logical replicas share one physical device).
+  devices exist; only on the CPU (the test path, where N logical
+  replicas share one physical device) do they alias the host device(s).
 
 Consumers: ``repro.serving.engine.Engine`` (real JAX engine, one placement
 per replica), ``repro.launch.serve`` (builds the set), ``repro.sim``
@@ -76,10 +76,11 @@ def make_replica_set(
 ) -> ReplicaSet:
     """Partition the fleet into ``num_replicas`` same-shape meshes.
 
-    With fewer devices than ``num_replicas * prod(mesh_shape)`` (the CPU
-    test path) every replica aliases the first ``prod(mesh_shape)`` host
-    devices. ``rules`` defaults to decode rules for ``num_kv_heads`` built
-    against the (identical) replica mesh.
+    With fewer devices than ``num_replicas * prod(mesh_shape)`` every
+    replica aliases the first ``prod(mesh_shape)`` host devices on the CPU
+    (the test path); on an accelerator that is an error. ``rules``
+    defaults to decode rules for ``num_kv_heads`` built against the
+    (identical) replica mesh.
     """
     import jax
     from jax.sharding import Mesh
@@ -89,6 +90,14 @@ def make_replica_set(
     per = int(np.prod(mesh_shape))
     if len(devices) >= num_replicas * per:
         groups = [devices[i * per:(i + 1) * per] for i in range(num_replicas)]
+    elif devices[0].platform != "cpu":
+        # an accelerator holds one replica's weights and pool; aliasing
+        # would silently stack every replica onto the first devices
+        raise ValueError(
+            f"{num_replicas} replicas of mesh {mesh_shape} need "
+            f"{num_replicas * per} {devices[0].platform} devices, "
+            f"have {len(devices)}"
+        )
     else:
         assert len(devices) >= per, (
             f"need {per} devices for mesh {mesh_shape}, have {len(devices)}"

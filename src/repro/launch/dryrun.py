@@ -57,17 +57,8 @@ def run_cell(
     compiled = lowered.compile()
     t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    # peak_memory_in_bytes landed after jax 0.4.x; args+out+temp is the
-    # proxy upper bound there, and peak_is_proxy marks artifact rows whose
-    # peak is the proxy so cross-version comparisons aren't silently mixed
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    peak_is_proxy = peak is None
-    if peak_is_proxy:
-        peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                + mem.temp_size_in_bytes)
+    peak = mem.peak_memory_in_bytes
     cost = compiled.cost_analysis()  # NOTE: counts while bodies ONCE
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     if hlo_dir is not None and key:
         hlo_dir.mkdir(parents=True, exist_ok=True)
@@ -90,7 +81,6 @@ def run_cell(
             "output_bytes": mem.output_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
             "peak_bytes": peak,
-            "peak_is_proxy": peak_is_proxy,
             "alias_bytes": mem.alias_size_in_bytes,
         },
         # (output, param) pairs XLA actually aliased — donation requests

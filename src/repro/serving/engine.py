@@ -200,6 +200,84 @@ def _chunk_prefill_impl_q(model, ctx, params, k_pages, v_pages, k_scale,
     return logits[0], k_pages, v_pages, k_scale, v_scale
 
 
+def _paged_decode_impl(model, ctx, params, k_pages, v_pages, tokens, lengths,
+                       tables, tail_pages, tail_offsets):
+    """One block-table decode step, pool-in/pool-out (jit body; the engine
+    donates the pool so the tail-page append is an in-place update)."""
+    logits, k_pages, v_pages = model.decode_paged(
+        params, k_pages, v_pages, tokens, lengths, tables,
+        tail_pages, tail_offsets, ctx=ctx,
+    )
+    return greedy_token(logits), k_pages, v_pages
+
+
+def _paged_decode_impl_q(model, ctx, params, k_pages, v_pages, k_scale,
+                         v_scale, tokens, lengths, tables, tail_pages,
+                         tail_offsets):
+    """Int8-resident decode step: scale sidecars ride in and out (the
+    tail-page requantize may grow them)."""
+    logits, k_pages, v_pages, k_scale, v_scale = model.decode_paged(
+        params, k_pages, v_pages, tokens, lengths, tables,
+        tail_pages, tail_offsets, k_scale, v_scale, ctx=ctx,
+    )
+    return greedy_token(logits), k_pages, v_pages, k_scale, v_scale
+
+
+def paged_decode_jit(model, ctx, quantized: bool = False):
+    """The engine's jitted decode step (pool donated)."""
+    if quantized:
+        return jax.jit(
+            functools.partial(_paged_decode_impl_q, model, ctx),
+            donate_argnums=(1, 2, 3, 4),
+        )
+    return jax.jit(
+        functools.partial(_paged_decode_impl, model, ctx), donate_argnums=(1, 2)
+    )
+
+
+def _abstract_weights_and_pool(cfg: ModelConfig, n_pages: int,
+                               page_tokens: int, sharding):
+    from repro.models.params import is_leaf
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(
+        lambda l: sds(l.shape, l.dtype), Model(cfg).describe(), is_leaf=is_leaf
+    )
+    pool = sds((cfg.num_layers, n_pages, page_tokens, cfg.num_kv_heads,
+                cfg.head_dim), jnp.bfloat16)
+    return params, pool, sds
+
+
+def decode_step_args(cfg: ModelConfig, *, n_pages: int, page_tokens: int,
+                     max_slots: int, table_pages: int, sharding=None) -> tuple:
+    """Abstract arguments of one bf16 paged decode step: the weights, a pool
+    of ``n_pages`` pages and one table bucket. Lowering the step with them
+    needs no device memory, so a pool size can be checked against HBM
+    before the pool exists (and compiled for a chip that is not attached)."""
+    params, pool, sds = _abstract_weights_and_pool(
+        cfg, n_pages, page_tokens, sharding
+    )
+    rows = sds((max_slots,))
+    return (params, pool, pool, rows, rows, sds((max_slots, table_pages)),
+            rows, rows)
+
+
+def chunk_step_args(cfg: ModelConfig, *, n_pages: int, page_tokens: int,
+                    prefix_pages: int, chunk_tokens: int,
+                    sharding=None) -> tuple:
+    """Abstract arguments of one bf16 chunk-prefill step (the twin of
+    :func:`decode_step_args`) at one (prefix-page, chunk) bucket."""
+    params, pool, sds = _abstract_weights_and_pool(
+        cfg, n_pages, page_tokens, sharding
+    )
+    scalar = sds(())
+    return (params, pool, pool, sds((prefix_pages,)),
+            sds((-(-chunk_tokens // page_tokens),)), sds((1, chunk_tokens)),
+            scalar, scalar, scalar, scalar, page_tokens)
+
+
 @functools.lru_cache(maxsize=None)
 def _chunk_prefill_fn(cfg: ModelConfig, quantized: bool = False):
     """Process-global jitted chunk prefill, keyed on the (hashable) model
@@ -313,6 +391,14 @@ class Engine:
             n_host_pages=n_host_pages,
             offload_format=offload_format,
             device_format=device_format,
+            # the pool lives on the replica's own devices, beside its
+            # weights (default placement would put every pool on device 0)
+            sharding=(
+                None if placement is None
+                else jax.sharding.NamedSharding(
+                    placement.mesh, jax.sharding.PartitionSpec()
+                )
+            ),
         )
         self.quantized = self.pool.quantized_device
         self.tree = TypedRadixTree(page_tokens)
@@ -340,16 +426,11 @@ class Engine:
                 self.pool.alloc_device() for _ in range(max_slots)
             ]
             self._table_bucket = table_bucket_pages
-            if self.quantized:
-                # the step rewrites tail-page scales alongside the payload,
-                # so the sidecars are donated (and re-adopted) too
-                self._paged_decode_fn = jax.jit(
-                    self._paged_decode_impl_q, donate_argnums=(1, 2, 3, 4)
-                )
-            else:
-                self._paged_decode_fn = jax.jit(
-                    self._paged_decode_impl, donate_argnums=(1, 2)
-                )
+            # an int8 step rewrites tail-page scales alongside the payload,
+            # so the sidecars are donated (and re-adopted) too
+            self._paged_decode_fn = paged_decode_jit(
+                self.model, self.ctx, self.quantized
+            )
             # chunked prefill: the process-global callable shares compiles
             # across engines; placement engines need their own ShardCtx
             if placement is None:
@@ -865,28 +946,6 @@ class Engine:
             params, cache, tokens, lengths, ctx=self.ctx
         )
         return greedy_token(logits), new_cache["k"], new_cache["v"]
-
-    def _paged_decode_impl(
-        self, params, k_pages, v_pages, tokens, lengths, tables,
-        tail_pages, tail_offsets,
-    ):
-        logits, k_pages, v_pages = self.model.decode_paged(
-            params, k_pages, v_pages, tokens, lengths, tables,
-            tail_pages, tail_offsets, ctx=self.ctx,
-        )
-        return greedy_token(logits), k_pages, v_pages
-
-    def _paged_decode_impl_q(
-        self, params, k_pages, v_pages, k_scale, v_scale, tokens, lengths,
-        tables, tail_pages, tail_offsets,
-    ):
-        """Int8-resident decode step: scale sidecars ride in and out (the
-        tail-page requantize may grow them)."""
-        logits, k_pages, v_pages, k_scale, v_scale = self.model.decode_paged(
-            params, k_pages, v_pages, tokens, lengths, tables,
-            tail_pages, tail_offsets, k_scale, v_scale, ctx=self.ctx,
-        )
-        return greedy_token(logits), k_pages, v_pages, k_scale, v_scale
 
     def step(self, active: "list[int] | None" = None) -> list[Completion]:
         """One continuous-batching decode step across the active slots.

@@ -37,12 +37,33 @@ KVSAN (``on_format``).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import kvsan
 from repro.kernels import kv_quant
+
+
+@partial(jax.jit, donate_argnums=0)
+def _set_page(arr, page, value):
+    """``arr[:, page] = value`` in place: the pool array is donated, so a
+    page reload neither copies the pool nor leaves a pool-sized hole in
+    device memory."""
+    return arr.at[:, page].set(value.astype(arr.dtype))
+
+
+def _filled(shape, dtype, value, sharding):
+    """A constant array made where ``sharding`` puts it. (``jnp.full`` with
+    a sharding fills on the default device and then copies, so every
+    replica's pool would pass through device 0.)"""
+    if sharding is None:
+        return jnp.full(shape, value, dtype)
+    return jax.jit(
+        lambda: jnp.full(shape, value, dtype), out_shardings=sharding
+    )()
 
 
 def scatter_token_run(k_arr, v_arr, page_idx, k_tokens, v_tokens, page_tokens):
@@ -135,7 +156,10 @@ class PagePool:
         dtype=jnp.bfloat16,
         offload_format: str = "bf16",
         device_format: str = "bf16",
+        sharding=None,
     ):
+        """``sharding`` places the device tier (``None``: JAX's default
+        device)."""
         self.layers = layers
         self.kv_heads = kv_heads
         self.head_dim = head_dim
@@ -152,15 +176,16 @@ class PagePool:
         self.quantized_device = self.device_format == "int8"
         shape = (layers, n_device_pages, page_tokens, kv_heads, head_dim)
         if self.quantized_device:
-            self.k = jnp.zeros(shape, jnp.int8)
-            self.v = jnp.zeros(shape, jnp.int8)
+            self.k = _filled(shape, jnp.int8, 0, sharding)
+            self.v = _filled(shape, jnp.int8, 0, sharding)
             # per-(layer, page) fp32 scale sidecars; 1.0 on a zero page is
             # as good as any scale (payload 0 dequantizes to 0)
-            self.k_scale = jnp.ones((layers, n_device_pages), jnp.float32)
-            self.v_scale = jnp.ones((layers, n_device_pages), jnp.float32)
+            scales = (layers, n_device_pages)
+            self.k_scale = _filled(scales, jnp.float32, 1, sharding)
+            self.v_scale = _filled(scales, jnp.float32, 1, sharding)
         else:
-            self.k = jnp.zeros(shape, dtype)
-            self.v = jnp.zeros(shape, dtype)
+            self.k = _filled(shape, dtype, 0, sharding)
+            self.v = _filled(shape, dtype, 0, sharding)
             self.k_scale = None
             self.v_scale = None
         hshape = (layers, n_host_pages, page_tokens, kv_heads, head_dim)
@@ -445,17 +470,13 @@ class PagePool:
         self._fmt_event("dev", dp, self.device_format)
         if self.offload_format == "int8":
             if self.quantized_device:
-                self.k = self.k.at[:, dp].set(
-                    jnp.asarray(self.host_k[:, host_page])
+                self.k = _set_page(self.k, dp, self.host_k[:, host_page])
+                self.v = _set_page(self.v, dp, self.host_v[:, host_page])
+                self.k_scale = _set_page(
+                    self.k_scale, dp, self.host_k_scale[:, host_page]
                 )
-                self.v = self.v.at[:, dp].set(
-                    jnp.asarray(self.host_v[:, host_page])
-                )
-                self.k_scale = self.k_scale.at[:, dp].set(
-                    jnp.asarray(self.host_k_scale[:, host_page])
-                )
-                self.v_scale = self.v_scale.at[:, dp].set(
-                    jnp.asarray(self.host_v_scale[:, host_page])
+                self.v_scale = _set_page(
+                    self.v_scale, dp, self.host_v_scale[:, host_page]
                 )
                 return dp
             kf = kv_quant.dequantize_np(
@@ -464,14 +485,14 @@ class PagePool:
             vf = kv_quant.dequantize_np(
                 self.host_v[:, host_page], self.host_v_scale[:, host_page]
             )
-            self.k = self.k.at[:, dp].set(jnp.asarray(kf, self.k.dtype))
-            self.v = self.v.at[:, dp].set(jnp.asarray(vf, self.v.dtype))
+            self.k = _set_page(self.k, dp, kf)
+            self.v = _set_page(self.v, dp, vf)
             return dp
-        self.k = self.k.at[:, dp].set(
-            jnp.asarray(self._decode_host(self.host_k[:, host_page]), self.k.dtype)
+        self.k = _set_page(
+            self.k, dp, self._decode_host(self.host_k[:, host_page])
         )
-        self.v = self.v.at[:, dp].set(
-            jnp.asarray(self._decode_host(self.host_v[:, host_page]), self.v.dtype)
+        self.v = _set_page(
+            self.v, dp, self._decode_host(self.host_v[:, host_page])
         )
         return dp
 

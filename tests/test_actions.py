@@ -6,11 +6,8 @@ plan is data, so a policy regression shows up as a diff against a literal.
 """
 from __future__ import annotations
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # image without hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import pytest
 

@@ -34,11 +34,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.core import SchedulerConfig
@@ -62,8 +59,8 @@ def _engine_pair():
     """One monolithic + one chunked engine, shared across the property
     examples (identical request sequences keep their radix trees, pools
     and jit caches in lockstep, so warm-prefix examples come for free).
-    Module-level rather than a fixture: ``@given``-drawn tests cannot
-    take fixture parameters under the hypothesis fallback shim."""
+    Module-level rather than a fixture: hypothesis refuses
+    function-scoped fixtures in ``@given`` tests."""
     if "pair" not in _shared:
         cfg, params = _cfg_params()
 

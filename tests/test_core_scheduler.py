@@ -1,11 +1,8 @@
 """Unit + property tests for MORI's three-tier scheduler (paper §4.3),
 driven through the PlacementPlan protocol."""
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # image without hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from _plan_driver import Driver
 from repro.core import (

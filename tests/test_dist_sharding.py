@@ -183,6 +183,37 @@ def test_replica_set_rejects_undersized_mesh():
         make_replica_set(1, mesh_shape=(2, 2), devices=jax.devices())
 
 
+def test_replicas_never_alias_an_accelerator():
+    """CPU replicas may share the host device; accelerator replicas each
+    need their own, or every weight copy and pool lands on device 0."""
+
+    class Chip:
+        platform = "tpu"
+
+    with pytest.raises(ValueError, match="4 replicas .* need 4 tpu devices, have 1"):
+        make_replica_set(4, devices=[Chip()])
+    rs = make_replica_set(4, devices=jax.devices()[:1])   # the CPU test path
+    assert len(rs) == 4
+
+
+def test_compile_cache_is_env_dir_or_fixed_checkout_dir(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.init_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before   # JAX reads the env
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        first = compile_cache.init_compile_cache()
+        assert first == compile_cache.init_compile_cache()
+        assert first == str(compile_cache.DEFAULT_DIR)
+        assert (compile_cache.DEFAULT_DIR.parent / "pyproject.toml").exists()
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_decode_rules_drive_a_real_decode_step():
     """The quickstart path in miniature: host-mesh ctx through prefill+decode."""
     from repro.models import ShardCtx

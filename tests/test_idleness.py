@@ -1,11 +1,8 @@
 """Unit + property tests for the windowed idleness metric (paper §4.2)."""
 import math
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # image without hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.idleness import IdlenessTracker
 from repro.core.types import Status

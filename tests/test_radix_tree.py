@@ -1,11 +1,8 @@
 """Tests for the typed radix tree (paper §4.3.2): prefix reuse + the
 tier-reversed type-priority eviction order."""
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # image without hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.radix_tree import TypedRadixTree
 from repro.core.types import TypeLabel

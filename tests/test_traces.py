@@ -1,11 +1,8 @@
 """Trace generator calibration against the paper's §3 statistics, plus IO."""
 import math
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # image without hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.traces import (
     busy_phase_durations,

@@ -14,6 +14,8 @@
 * :mod:`repro.analysis.jitaudit` — static compile-plane auditor:
   donation verification, retrace-hazard probes, and static rooflines
   over the traced jaxprs/HLO (``python -m repro.analysis.jitaudit``).
+* :mod:`repro.analysis.spans` — host spans and self-time counters of
+  the serving path (always on; read from ``RouterMetrics``).
 
 This ``__init__`` stays import-light on purpose: ``kvpool`` and
 ``radix_tree`` import :mod:`repro.analysis.kvsan` at module load, so

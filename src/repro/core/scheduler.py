@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import abc
 
+from repro.analysis.spans import HostTimes, region
 from repro.core.actions import (
     Action,
     CancelTransfer,
@@ -97,6 +98,13 @@ class AgentScheduler(abc.ABC):
         # the bytes still physically occupy, so the eventual Forward carries
         # the true source instead of pretending the KV is warm.
         self._pending_source: dict[str, Tier] = {}
+        # serving-thread time of the entry points below (a router replaces
+        # this with its metrics; the simulator leaves it private)
+        self.host_times = HostTimes()
+
+    def _region(self, name: str, **meta) -> region:
+        """The span and self-time counter of one scheduler entry point."""
+        return region(f"scheduler.{name}", (self.host_times, "sched_host_s"), **meta)
 
     # -------------------------------------------------------------- events
     def program_arrived(
@@ -114,17 +122,19 @@ class AgentScheduler(abc.ABC):
         format equals the device format and every byte figure collapses
         to ``kv_bytes_per_token``, reproducing pre-format accounting
         exactly."""
-        prog = ProgramState(pid, kv_bytes_per_token, arrived_at=now)
-        prog.wire_bytes_per_token = wire_bytes_per_token
-        prog.set_window(self.config.idleness_window)
-        self.programs[pid] = prog
-        self.waiting.add(prog)
-        return prog
+        with self._region("program_arrived", pid=pid):
+            prog = ProgramState(pid, kv_bytes_per_token, arrived_at=now)
+            prog.wire_bytes_per_token = wire_bytes_per_token
+            prog.set_window(self.config.idleness_window)
+            self.programs[pid] = prog
+            self.waiting.add(prog)
+            return prog
 
     def request_arrived(self, pid: str, input_tokens: int, now: float) -> PlacementPlan:
-        self._now = now
-        self._on_request_arrived(pid, input_tokens, now)
-        return self._drain(now)
+        with self._region("request_arrived", pid=pid):
+            self._now = now
+            self._on_request_arrived(pid, input_tokens, now)
+            return self._drain(now)
 
     def notify_inference_started(self, pid: str, now: float) -> None:
         """The runtime started executing a forwarded request (no actions)."""
@@ -134,25 +144,28 @@ class AgentScheduler(abc.ABC):
             self._running[prog.replica].add(pid)
 
     def request_completed(self, pid: str, output_tokens: int, now: float) -> PlacementPlan:
-        self._now = now
-        self._on_request_completed(pid, output_tokens, now)
-        return self._drain(now)
+        with self._region("request_completed", pid=pid):
+            self._now = now
+            self._on_request_completed(pid, output_tokens, now)
+            return self._drain(now)
 
     def program_finished(self, pid: str, now: float) -> PlacementPlan:
-        self._now = now
-        prog = self.programs.pop(pid, None)
-        if prog is not None:
-            prog.finished = True
-            if prog.replica is not None:
-                self._running[prog.replica].discard(pid)
-            self._release(prog)
-            self.ledger.drop_pid(pid)
-        return self._drain(now)
+        with self._region("program_finished", pid=pid):
+            self._now = now
+            prog = self.programs.pop(pid, None)
+            if prog is not None:
+                prog.finished = True
+                if prog.replica is not None:
+                    self._running[prog.replica].discard(pid)
+                self._release(prog)
+                self.ledger.drop_pid(pid)
+            return self._drain(now)
 
     def tick(self, now: float) -> PlacementPlan:
-        self._now = now
-        self._on_tick(now)
-        return self._drain(now)
+        with self._region("tick"):
+            self._now = now
+            self._on_tick(now)
+            return self._drain(now)
 
     def on_transfer_complete(self, pid: str, action_id: int, now: float) -> PlacementPlan:
         """Runtime acknowledgement that a transfer finished. Closes the
@@ -160,11 +173,12 @@ class AgentScheduler(abc.ABC):
         replica) are tolerated. Policies react to landed bytes through the
         ``_on_transfer_complete`` hook (e.g. promoting a migrated program
         only once its DRAM copy physically exists on the destination)."""
-        self._now = now
-        rec = self.ledger.complete(action_id)
-        if rec is not None:
-            self._on_transfer_complete(rec, now)
-        return self._drain(now)
+        with self._region("on_transfer_complete", pid=pid):
+            self._now = now
+            rec = self.ledger.complete(action_id)
+            if rec is not None:
+                self._on_transfer_complete(rec, now)
+            return self._drain(now)
 
     def _on_transfer_complete(self, rec: TransferRecord, now: float) -> None:
         """Policy hook: the transfer behind ``rec`` has fully landed."""
@@ -174,9 +188,10 @@ class AgentScheduler(abc.ABC):
         (a resident program finished its step while others keep decoding).
         Policies use the hook to forward gated work into the freed slot
         immediately instead of waiting for the next tick."""
-        self._now = now
-        self._on_slot_freed(replica, now)
-        return self._drain(now)
+        with self._region("on_slot_freed"):
+            self._now = now
+            self._on_slot_freed(replica, now)
+            return self._drain(now)
 
     def _on_slot_freed(self, replica: int, now: float) -> None:
         """Policy hook: a decode slot on ``replica`` is free again."""

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import compile_tracker
+from repro.analysis.spans import region
 from repro.core.radix_tree import TypedRadixTree
 from repro.core.types import Tier, TypeLabel
 from repro.dist import ReplicaPlacement
@@ -401,6 +402,8 @@ class Engine:
             ),
         )
         self.quantized = self.pool.quantized_device
+        # host-time counters (the router replaces them with its metrics)
+        self.host_times = self.pool.host_times
         self.tree = TypedRadixTree(page_tokens)
         if self.pool._san is not None:
             # give the sanitizer the node graph (pin checks) and the live
@@ -648,84 +651,86 @@ class Engine:
         pid = req.program_id
         self._san_scope(f"submit:{pid}")
 
-        # 1. promote any host-resident prefix pages back to the device
-        reloaded = self._reload_prefix(req.tokens)
-        # 2. device-resident prefix
-        nodes = self.tree.match_prefix(req.tokens)
-        cached = len(nodes) * self.page_tokens
-        pages = [n.device_page for n in nodes]
-        suffix = req.tokens[cached:]
-        assert suffix, "request must extend its cached prefix"
+        with region("engine.admit", (self.host_times, "prefill_host_s"), pid=pid):
+            # 1. promote any host-resident prefix pages back to the device
+            reloaded = self._reload_prefix(req.tokens)
+            # 2. device-resident prefix
+            nodes = self.tree.match_prefix(req.tokens)
+            cached = len(nodes) * self.page_tokens
+            pages = [n.device_page for n in nodes]
+            suffix = req.tokens[cached:]
+            assert suffix, "request must extend its cached prefix"
 
-        # pin before touching the pool: suffix-page allocation below may
-        # evict, and the prefix chain a block table points at must survive.
-        # tree.pin covers the program's own nodes; the matched chain is
-        # refcount-held separately because a shared prefix may belong to a
-        # different program (released in _finish)
-        self.tree.pin(pid)
-        if not self.dense_slots:
-            self.tree.acquire_nodes(nodes)
+            # pin before touching the pool: suffix-page allocation below may
+            # evict, and the prefix chain a block table points at must survive.
+            # tree.pin covers the program's own nodes; the matched chain is
+            # refcount-held separately because a shared prefix may belong to a
+            # different program (released in _finish)
+            self.tree.pin(pid)
+            if not self.dense_slots:
+                self.tree.acquire_nodes(nodes)
 
-        prefix = None
-        if pages:
-            pk, pv = self.pool.read_device_pages(pages)
-            prefix = {"k": pk[:, None], "v": pv[:, None]}       # [L,1,Sp,KH,HD]
+            prefix = None
+            if pages:
+                pk, pv = self.pool.read_device_pages(pages)
+                prefix = {"k": pk[:, None], "v": pv[:, None]}       # [L,1,Sp,KH,HD]
 
-        pad = (-len(suffix)) % self.prefill_bucket
-        batch = {"tokens": jnp.asarray([suffix + [0] * pad], jnp.int32)}
-        logits, cache = self.model.prefill(
-            self.params, batch, ctx=self.ctx, prefix=prefix,
-            logit_index=len(suffix) - 1,
-        )
-        first_token = int(greedy_token(logits[0]))
+            pad = (-len(suffix)) % self.prefill_bucket
+            batch = {"tokens": jnp.asarray([suffix + [0] * pad], jnp.int32)}
+            logits, cache = self.model.prefill(
+                self.params, batch, ctx=self.ctx, prefix=prefix,
+                logit_index=len(suffix) - 1,
+            )
+            with region("engine.chunk_wait", pid=pid):
+                first_token = int(greedy_token(logits[0]))
 
-        # 3. install into a decode slot
-        sid = self._free_slots.pop()
-        length = len(req.tokens)
-        slot = _Slot(
-            request=req,
-            slot_id=sid,
-            length=length,
-            produced=[first_token],
-            cached_tokens=cached,
-            prefilled_tokens=len(suffix),
-            reloaded_pages=reloaded,
-        )
-        k_suf = cache["k"][:, 0, : len(suffix)]                 # [L,Ssuf,KH,HD]
-        v_suf = cache["v"][:, 0, : len(suffix)]
-        if self.dense_slots:
-            k_ctx, v_ctx = k_suf, v_suf
-            if prefix is not None:
-                k_ctx = jnp.concatenate([prefix["k"][:, 0], k_ctx], axis=1)
-                v_ctx = jnp.concatenate([prefix["v"][:, 0], v_ctx], axis=1)
-            self.slot_k = self.slot_k.at[:, sid, :length].set(k_ctx)
-            self.slot_v = self.slot_v.at[:, sid, :length].set(v_ctx)
-        else:
-            # block-table install: reference the cached prefix pages and
-            # write the suffix KV straight into freshly-allocated pool
-            # pages — no dense materialization, no write-back at finish
-            T = self.page_tokens
-            slot.table = list(pages)
-            slot.owned_from = len(pages)
-            slot.prefix_nodes = nodes
-            new_pages: list[int] = []
-            try:
-                for _ in range(len(pages), -(-length // T)):
-                    new_pages.append(self._alloc_decode_page())
-            except RuntimeError:
-                for page in new_pages:
-                    self.pool.free_device(page)
-                self.tree.release_nodes(nodes)
-                self.tree.unpin(pid)
-                self._free_slots.append(sid)
-                raise
-            slot.table.extend(new_pages)
-            self.pool.write_device_pages(new_pages, k_suf, v_suf)
-        self.lengths[sid] = length
-        self.last_token[sid] = first_token
-        self._tail_token[sid] = req.tokens[-1]  # prefill wrote its KV last
-        self.slots[sid] = slot
-        return sid
+            # 3. install into a decode slot
+            sid = self._free_slots.pop()
+            length = len(req.tokens)
+            slot = _Slot(
+                request=req,
+                slot_id=sid,
+                length=length,
+                produced=[first_token],
+                cached_tokens=cached,
+                prefilled_tokens=len(suffix),
+                reloaded_pages=reloaded,
+            )
+            k_suf = cache["k"][:, 0, : len(suffix)]                 # [L,Ssuf,KH,HD]
+            v_suf = cache["v"][:, 0, : len(suffix)]
+            if self.dense_slots:
+                k_ctx, v_ctx = k_suf, v_suf
+                if prefix is not None:
+                    k_ctx = jnp.concatenate([prefix["k"][:, 0], k_ctx], axis=1)
+                    v_ctx = jnp.concatenate([prefix["v"][:, 0], v_ctx], axis=1)
+                self.slot_k = self.slot_k.at[:, sid, :length].set(k_ctx)
+                self.slot_v = self.slot_v.at[:, sid, :length].set(v_ctx)
+            else:
+                # block-table install: reference the cached prefix pages and
+                # write the suffix KV straight into freshly-allocated pool
+                # pages — no dense materialization, no write-back at finish
+                T = self.page_tokens
+                slot.table = list(pages)
+                slot.owned_from = len(pages)
+                slot.prefix_nodes = nodes
+                new_pages: list[int] = []
+                try:
+                    for _ in range(len(pages), -(-length // T)):
+                        new_pages.append(self._alloc_decode_page())
+                except RuntimeError:
+                    for page in new_pages:
+                        self.pool.free_device(page)
+                    self.tree.release_nodes(nodes)
+                    self.tree.unpin(pid)
+                    self._free_slots.append(sid)
+                    raise
+                slot.table.extend(new_pages)
+                self.pool.write_device_pages(new_pages, k_suf, v_suf)
+            self.lengths[sid] = length
+            self.last_token[sid] = first_token
+            self._tail_token[sid] = req.tokens[-1]  # prefill wrote its KV last
+            self.slots[sid] = slot
+            return sid
 
     # --------------------------------------------------- chunked prefill
     def begin_submit(self, req: EngineRequest) -> PrefillJob:
@@ -744,46 +749,47 @@ class Engine:
         pid = req.program_id
         self._san_scope(f"begin_submit:{pid}")
 
-        reloaded = self._reload_prefix(req.tokens)
-        nodes = self.tree.match_prefix(req.tokens)
-        cached = len(nodes) * self.page_tokens
-        pages = [n.device_page for n in nodes]
-        suffix = req.tokens[cached:]
-        assert suffix, "request must extend its cached prefix"
+        with region("engine.admit", (self.host_times, "prefill_host_s"), pid=pid):
+            reloaded = self._reload_prefix(req.tokens)
+            nodes = self.tree.match_prefix(req.tokens)
+            cached = len(nodes) * self.page_tokens
+            pages = [n.device_page for n in nodes]
+            suffix = req.tokens[cached:]
+            assert suffix, "request must extend its cached prefix"
 
-        self.tree.pin(pid)
-        self.tree.acquire_nodes(nodes)
-        sid = self._free_slots.pop()
-        T = self.page_tokens
-        new_pages: list[int] = []
-        try:
-            for _ in range(len(pages), -(-len(req.tokens) // T)):
-                new_pages.append(self._alloc_decode_page())
-        except RuntimeError:
-            for page in new_pages:
-                self.pool.free_device(page)
-            self.tree.release_nodes(nodes)
-            self.tree.unpin(pid)
-            self._free_slots.append(sid)
-            raise
-        hold = None
-        if self.pool._san is not None:
-            # the staged suffix pages belong to this job until the final
-            # chunk installs them into a slot's block table
-            hold = self.pool._san.add_hold(
-                "dev", new_pages, f"prefill job:{pid}"
+            self.tree.pin(pid)
+            self.tree.acquire_nodes(nodes)
+            sid = self._free_slots.pop()
+            T = self.page_tokens
+            new_pages: list[int] = []
+            try:
+                for _ in range(len(pages), -(-len(req.tokens) // T)):
+                    new_pages.append(self._alloc_decode_page())
+            except RuntimeError:
+                for page in new_pages:
+                    self.pool.free_device(page)
+                self.tree.release_nodes(nodes)
+                self.tree.unpin(pid)
+                self._free_slots.append(sid)
+                raise
+            hold = None
+            if self.pool._san is not None:
+                # the staged suffix pages belong to this job until the final
+                # chunk installs them into a slot's block table
+                hold = self.pool._san.add_hold(
+                    "dev", new_pages, f"prefill job:{pid}"
+                )
+            return PrefillJob(
+                request=req,
+                slot_id=sid,
+                suffix=suffix,
+                cached_tokens=cached,
+                reloaded_pages=reloaded,
+                prefix_pages=pages,
+                prefix_nodes=nodes,
+                new_pages=new_pages,
+                kvsan_hold=hold,
             )
-        return PrefillJob(
-            request=req,
-            slot_id=sid,
-            suffix=suffix,
-            cached_tokens=cached,
-            reloaded_pages=reloaded,
-            prefix_pages=pages,
-            prefix_nodes=nodes,
-            new_pages=new_pages,
-            kvsan_hold=hold,
-        )
 
     def prefill_step(self, job: PrefillJob, token_budget: int | None = None) -> bool:
         """Run ONE bucketed prefill chunk of at most ``token_budget`` tokens
@@ -804,49 +810,52 @@ class Engine:
         budget = self.prefill_chunk_tokens if token_budget is None else token_budget
         cap = max(T, (budget // T) * T)          # page-aligned chunk ceiling
         take = min(job.remaining, cap)
-        c_pad = -(-take // self.prefill_bucket) * self.prefill_bucket
-        scratch = self._scratch_pages[job.slot_id]
+        pid = job.request.program_id
+        with region("engine.chunk", (self.host_times, "prefill_host_s"), pid=pid, tokens=take):
+            c_pad = -(-take // self.prefill_bucket) * self.prefill_bucket
+            scratch = self._scratch_pages[job.slot_id]
 
-        # prefix for this chunk: radix pages + suffix pages already written
-        # (the cursor is page-aligned on every chunk but the last)
-        prefix_pages = job.prefix_pages + job.new_pages[: job.cursor // T]
-        p_real = len(prefix_pages)
-        p_pad = -(-p_real // self._table_bucket) * self._table_bucket
-        prefix_idx = prefix_pages + [scratch] * (p_pad - p_real)
+            # prefix for this chunk: radix pages + suffix pages already written
+            # (the cursor is page-aligned on every chunk but the last)
+            prefix_pages = job.prefix_pages + job.new_pages[: job.cursor // T]
+            p_real = len(prefix_pages)
+            p_pad = -(-p_real // self._table_bucket) * self._table_bucket
+            prefix_idx = prefix_pages + [scratch] * (p_pad - p_real)
 
-        # staged pages this chunk writes, padded to the bucketed width with
-        # the slot's scratch page (pad lanes scatter zeros — harmless)
-        w0 = job.cursor // T
-        w_real = -(-take // T)
-        w_pad = -(-c_pad // T)
-        write_idx = job.new_pages[w0 : w0 + w_real]
-        write_idx = write_idx + [scratch] * (w_pad - len(write_idx))
+            # staged pages this chunk writes, padded to the bucketed width with
+            # the slot's scratch page (pad lanes scatter zeros — harmless)
+            w0 = job.cursor // T
+            w_real = -(-take // T)
+            w_pad = -(-c_pad // T)
+            write_idx = job.new_pages[w0 : w0 + w_real]
+            write_idx = write_idx + [scratch] * (w_pad - len(write_idx))
 
-        chunk = job.suffix[job.cursor : job.cursor + take]
-        tokens = jnp.asarray([chunk + [0] * (c_pad - take)], jnp.int32)
-        pos0 = job.cached_tokens + job.cursor    # absolute chunk start
-        k_pages, v_pages = self.pool.block_table_view()
-        sidecars = self.pool.scale_view() if self.quantized else ()
-        out = self._chunk_fn(
-            self.params, k_pages, v_pages, *sidecars,
-            jnp.asarray(prefix_idx, jnp.int32),
-            jnp.asarray(write_idx, jnp.int32),
-            tokens,
-            jnp.int32(pos0),                     # prefix_valid == chunk start
-            jnp.int32(pos0),
-            jnp.int32(take),
-            jnp.int32(take - 1),                 # final-chunk logit position
-            T,
-        )
-        logits = out[0]
-        self.pool.adopt(*out[1:])
-        job.cursor += take
-        job.chunks_run += 1
-        if job.cursor < len(job.suffix):
-            return False
-        job.first_token = int(greedy_token(logits))
-        self._install_job(job)
-        return True
+            chunk = job.suffix[job.cursor : job.cursor + take]
+            tokens = jnp.asarray([chunk + [0] * (c_pad - take)], jnp.int32)
+            pos0 = job.cached_tokens + job.cursor    # absolute chunk start
+            k_pages, v_pages = self.pool.block_table_view()
+            sidecars = self.pool.scale_view() if self.quantized else ()
+            out = self._chunk_fn(
+                self.params, k_pages, v_pages, *sidecars,
+                jnp.asarray(prefix_idx, jnp.int32),
+                jnp.asarray(write_idx, jnp.int32),
+                tokens,
+                jnp.int32(pos0),                     # prefix_valid == chunk start
+                jnp.int32(pos0),
+                jnp.int32(take),
+                jnp.int32(take - 1),                 # final-chunk logit position
+                T,
+            )
+            logits = out[0]
+            self.pool.adopt(*out[1:])
+            job.cursor += take
+            job.chunks_run += 1
+            if job.cursor < len(job.suffix):
+                return False
+            with region("engine.chunk_wait", pid=pid):
+                job.first_token = int(greedy_token(logits))
+            self._install_job(job)
+            return True
 
     def _install_job(self, job: PrefillJob) -> None:
         """Final chunk landed: install the job's slot for decode (the
@@ -977,36 +986,41 @@ class Engine:
             if not active_ids:
                 return []
         self.steps += 1
-        active_set = set(active_ids)
-        toks_np = self.last_token.copy()
-        for sid in self.slots:
-            if sid in active_set:
-                # this step writes last_token's KV at the new tail position
-                self._tail_token[sid] = self.last_token[sid]
-                self.lengths[sid] += 1  # the decoded token extends the ctx
+        n = self.steps
+        counter = (self.host_times, "decode_host_s")
+        with region("engine.decode_prep", counter, step=n, rows=len(active_ids)):
+            active_set = set(active_ids)
+            toks_np = self.last_token.copy()
+            for sid in self.slots:
+                if sid in active_set:
+                    # this step writes last_token's KV at the new tail position
+                    self._tail_token[sid] = self.last_token[sid]
+                    self.lengths[sid] += 1  # the decoded token extends the ctx
+                else:
+                    # masked: rewrite the existing tail KV instead of clobbering
+                    # it with the (not-yet-written) last token's
+                    toks_np[sid] = self._tail_token[sid]
+            toks = jnp.asarray(toks_np, jnp.int32)
+            lens = jnp.asarray(np.maximum(self.lengths, 1), jnp.int32)
+            if self.dense_slots:
+                next_tok, self.slot_k, self.slot_v = self._decode_fn(
+                    self.params, self.slot_k, self.slot_v, toks, lens
+                )
             else:
-                # masked: rewrite the existing tail KV instead of clobbering
-                # it with the (not-yet-written) last token's
-                toks_np[sid] = self._tail_token[sid]
-        toks = jnp.asarray(toks_np, jnp.int32)
-        lens = jnp.asarray(np.maximum(self.lengths, 1), jnp.int32)
-        if self.dense_slots:
-            next_tok, self.slot_k, self.slot_v = self._decode_fn(
-                self.params, self.slot_k, self.slot_v, toks, lens
-            )
-        else:
-            next_tok = self._paged_step(toks, lens)
-        next_tok = np.asarray(next_tok)
+                next_tok = self._paged_step(toks, lens)
+            with region("engine.decode_wait", step=n):
+                next_tok = np.asarray(next_tok)
         done: list[Completion] = []
-        for sid, slot in list(self.slots.items()):
-            if sid not in active_set:
-                continue
-            slot.length = int(self.lengths[sid])
-            tok = int(next_tok[sid])
-            slot.produced.append(tok)
-            self.last_token[sid] = tok
-            if len(slot.produced) >= slot.request.max_new_tokens:
-                done.append(self._finish(slot))
+        with region("engine.decode_finish", counter, step=n):
+            for sid, slot in list(self.slots.items()):
+                if sid not in active_set:
+                    continue
+                slot.length = int(self.lengths[sid])
+                tok = int(next_tok[sid])
+                slot.produced.append(tok)
+                self.last_token[sid] = tok
+                if len(slot.produced) >= slot.request.max_new_tokens:
+                    done.append(self._finish(slot))
         return done
 
     def slot_progress(self) -> dict[int, tuple[str, int, int]]:

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import kvsan
+from repro.analysis.spans import HostTimes, region
 from repro.kernels import kv_quant
 
 
@@ -212,6 +213,9 @@ class PagePool:
         self.n_host_pages = n_host_pages
         self.offload_bytes = 0
         self.reload_bytes = 0
+        # serving-thread time of the page copies (the router replaces this
+        # with its metrics)
+        self.host_times = HostTimes()
         # page-lifetime sanitizer (None unless REPRO_KVSAN=1): every
         # alloc/free/read/write verb below reports to it
         self._san = kvsan.maybe_sanitizer(
@@ -426,33 +430,35 @@ class PagePool:
         speculative, and a cancelled transfer must leave no round-trip
         trace in :class:`PoolStats`. The committing caller bills via
         :meth:`bill_offload` (the atomic verbs below do it themselves)."""
-        if self._san is not None:
-            self._san.on_read("dev", dev_page)
-        hp = self.alloc_host()
-        if hp is None:
-            return None
-        if self._san is not None:
-            self._san.on_write("host", hp)
-        self._fmt_event("host", hp, self.offload_format)
-        if self.offload_format == "int8":
-            if self.quantized_device:
-                self.host_k[:, hp] = np.asarray(self.k[:, dev_page])
-                self.host_v[:, hp] = np.asarray(self.v[:, dev_page])
-                self.host_k_scale[:, hp] = np.asarray(self.k_scale[:, dev_page])
-                self.host_v_scale[:, hp] = np.asarray(self.v_scale[:, dev_page])
-            else:
-                kf = np.asarray(self.k[:, dev_page].astype(jnp.float32))
-                vf = np.asarray(self.v[:, dev_page].astype(jnp.float32))
-                self.host_k[:, hp], self.host_k_scale[:, hp] = (
-                    kv_quant.quantize_np(kf)
-                )
-                self.host_v[:, hp], self.host_v_scale[:, hp] = (
-                    kv_quant.quantize_np(vf)
-                )
+        with region("pool.copy_page_to_host", (self.host_times, "transfer_host_s"),
+                    page=dev_page):
+            if self._san is not None:
+                self._san.on_read("dev", dev_page)
+            hp = self.alloc_host()
+            if hp is None:
+                return None
+            if self._san is not None:
+                self._san.on_write("host", hp)
+            self._fmt_event("host", hp, self.offload_format)
+            if self.offload_format == "int8":
+                if self.quantized_device:
+                    self.host_k[:, hp] = np.asarray(self.k[:, dev_page])
+                    self.host_v[:, hp] = np.asarray(self.v[:, dev_page])
+                    self.host_k_scale[:, hp] = np.asarray(self.k_scale[:, dev_page])
+                    self.host_v_scale[:, hp] = np.asarray(self.v_scale[:, dev_page])
+                else:
+                    kf = np.asarray(self.k[:, dev_page].astype(jnp.float32))
+                    vf = np.asarray(self.v[:, dev_page].astype(jnp.float32))
+                    self.host_k[:, hp], self.host_k_scale[:, hp] = (
+                        kv_quant.quantize_np(kf)
+                    )
+                    self.host_v[:, hp], self.host_v_scale[:, hp] = (
+                        kv_quant.quantize_np(vf)
+                    )
+                return hp
+            self.host_k[:, hp] = self._encode_host(self.k[:, dev_page])
+            self.host_v[:, hp] = self._encode_host(self.v[:, dev_page])
             return hp
-        self.host_k[:, hp] = self._encode_host(self.k[:, dev_page])
-        self.host_v[:, hp] = self._encode_host(self.v[:, dev_page])
-        return hp
 
     def copy_page_to_device(self, host_page: int) -> int | None:
         """Stage one host page into a device page *without* freeing the
@@ -460,41 +466,43 @@ class PagePool:
         int8 host page lands verbatim on an int8-resident pool (payload +
         scales, byte-identical) and dequantizes to the logical dtype on a
         bf16 pool."""
-        if self._san is not None:
-            self._san.on_read("host", host_page)
-        dp = self.alloc_device()
-        if dp is None:
-            return None
-        if self._san is not None:
-            self._san.on_write("dev", dp)
-        self._fmt_event("dev", dp, self.device_format)
-        if self.offload_format == "int8":
-            if self.quantized_device:
-                self.k = _set_page(self.k, dp, self.host_k[:, host_page])
-                self.v = _set_page(self.v, dp, self.host_v[:, host_page])
-                self.k_scale = _set_page(
-                    self.k_scale, dp, self.host_k_scale[:, host_page]
+        with region("pool.copy_page_to_device", (self.host_times, "transfer_host_s"),
+                    page=host_page):
+            if self._san is not None:
+                self._san.on_read("host", host_page)
+            dp = self.alloc_device()
+            if dp is None:
+                return None
+            if self._san is not None:
+                self._san.on_write("dev", dp)
+            self._fmt_event("dev", dp, self.device_format)
+            if self.offload_format == "int8":
+                if self.quantized_device:
+                    self.k = _set_page(self.k, dp, self.host_k[:, host_page])
+                    self.v = _set_page(self.v, dp, self.host_v[:, host_page])
+                    self.k_scale = _set_page(
+                        self.k_scale, dp, self.host_k_scale[:, host_page]
+                    )
+                    self.v_scale = _set_page(
+                        self.v_scale, dp, self.host_v_scale[:, host_page]
+                    )
+                    return dp
+                kf = kv_quant.dequantize_np(
+                    self.host_k[:, host_page], self.host_k_scale[:, host_page]
                 )
-                self.v_scale = _set_page(
-                    self.v_scale, dp, self.host_v_scale[:, host_page]
+                vf = kv_quant.dequantize_np(
+                    self.host_v[:, host_page], self.host_v_scale[:, host_page]
                 )
+                self.k = _set_page(self.k, dp, kf)
+                self.v = _set_page(self.v, dp, vf)
                 return dp
-            kf = kv_quant.dequantize_np(
-                self.host_k[:, host_page], self.host_k_scale[:, host_page]
+            self.k = _set_page(
+                self.k, dp, self._decode_host(self.host_k[:, host_page])
             )
-            vf = kv_quant.dequantize_np(
-                self.host_v[:, host_page], self.host_v_scale[:, host_page]
+            self.v = _set_page(
+                self.v, dp, self._decode_host(self.host_v[:, host_page])
             )
-            self.k = _set_page(self.k, dp, kf)
-            self.v = _set_page(self.v, dp, vf)
             return dp
-        self.k = _set_page(
-            self.k, dp, self._decode_host(self.host_k[:, host_page])
-        )
-        self.v = _set_page(
-            self.v, dp, self._decode_host(self.host_v[:, host_page])
-        )
-        return dp
 
     def import_host_page(self, src_pool: "PagePool", src_hp: int) -> int | None:
         """Copy one host page from *another replica's* pool into this pool's

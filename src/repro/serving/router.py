@@ -32,7 +32,7 @@ admission goes through the engine's two-phase ``begin_submit`` /
 (owning its engine slot, visible to occupancy probes, never stepping)
 while the pump runs one ``prefill_token_budget``-bounded chunk per settle
 visit, and due decode steps interleave between chunks instead of stalling
-behind a whole prefill (``RouterMetrics.prefill_interleaved_steps``).
+behind a whole prefill.
 Chunk shapes are bucketed so the jitted chunk prefill compiles once per
 bucket process-wide — monolithic submit re-traces per context length —
 which is where the measured TTFT win (``RouterMetrics.ttft_s``) comes
@@ -99,6 +99,7 @@ from dataclasses import dataclass, field
 
 from repro.analysis import compile_tracker, kvsan
 from repro.analysis.invariants import ControlPlaneChecker
+from repro.analysis.spans import HostTimes, region
 from repro.core import SCHEDULERS, SchedulerConfig, TierCapacity
 from repro.core.actions import (
     Action,
@@ -124,7 +125,12 @@ _MIN_SYNTH_CTX = 16
 
 
 @dataclass
-class RouterMetrics:
+class RouterMetrics(HostTimes):
+    """The serving path's counters. The host-time fields of
+    :class:`~repro.analysis.spans.HostTimes` are filled by the regions of
+    every layer the router hands this object to (engines, pools, planes,
+    scheduler)."""
+
     steps_completed: int = 0
     tokens_generated: int = 0
     cached_tokens: int = 0
@@ -150,10 +156,8 @@ class RouterMetrics:
     peak_live_slots: int = 0         # most slots one step ever advanced
     multi_slot_steps: int = 0        # steps that advanced ≥ 2 slots
     slot_wait_s: float = 0.0         # Forward release → engine-submit wait
-    slot_waits: int = 0              # submits that waited on a full batch
     # chunked prefill (zero when chunked_prefill is off)
     prefill_chunks: int = 0          # prefill_step calls executed by the pump
-    prefill_interleaved_steps: int = 0  # decode steps with a prefill in flight
     # multi-replica scale-out: cross-replica migration and drain/failover
     migrations: int = 0              # Migrate actions executed
     migrated_pages: int = 0          # pages landed on a migrate destination
@@ -383,6 +387,11 @@ class MoriRouter:
             )
             for i, eng in enumerate(engines)
         ]
+        # every layer below counts its host time into the router's metrics
+        self.sched.host_times = self.metrics
+        for eng, plane in zip(engines, self.planes):
+            eng.host_times = eng.pool.host_times = self.metrics
+            plane.host_times = self.metrics
 
     # -------------------------------------------------------------- helpers
     @property
@@ -479,44 +488,45 @@ class MoriRouter:
         """Execute a scheduler plan as real page movements — queueing
         transfer-bearing actions on the async planes, or executing and
         acknowledging them synchronously in ``sync_transfers`` mode."""
-        if self.record_plans and plan.actions:
-            self.action_log.extend(plan.actions)
-        for act in plan:
-            if isinstance(act, Forward):
-                self._exec_forward(act, plan.now)
-            elif isinstance(act, Offload):
-                self._exec_offload(act, plan.now)
-            elif isinstance(act, Discard):
-                if act.replica is not None:
-                    # abort any copy still streaming this program's pages.
-                    # On program teardown the ledger already dropped the
-                    # records; on a live-program eviction (CPU/SSD overflow
-                    # passes) they are still open, and must be closed here —
-                    # a stale open offload would later match
-                    # _cancel_inflight_offload and cancel the wrong transfer
-                    if not self.sync_transfers:
-                        for job, rolled in self.planes[act.replica].abort_pid(
-                            act.pid, plan.now
-                        ):
-                            self.metrics.cancelled_pages += rolled
-                            if self.sched.ledger.is_open(job.action_id):
-                                self.sched.ledger.cancel(job.action_id)
-                    # the logical SSD tier is backed by the host pool on the
-                    # real path — freeing it frees host pages
-                    tier = Tier.CPU if act.tier is Tier.SSD else act.tier
-                    self.engines[act.replica].discard_program(act.pid, tier)
-            elif isinstance(act, SetLabel):
-                if act.replica is not None:
-                    self.engines[act.replica].set_label(act.pid, act.label)
-            elif isinstance(act, CancelTransfer):
-                self._exec_cancel(act, plan.now)
-            elif isinstance(act, Migrate):
-                self._exec_migrate(act, plan.now)
-        self.metrics.peak_inflight_bytes = max(
-            self.metrics.peak_inflight_bytes, self.sched.ledger.in_flight_bytes()
-        )
-        if self._checker is not None:
-            self._checker.check(plan.now)
+        with region("router.apply_plan", (self.metrics, "sched_host_s")):
+            if self.record_plans and plan.actions:
+                self.action_log.extend(plan.actions)
+            for act in plan:
+                if isinstance(act, Forward):
+                    self._exec_forward(act, plan.now)
+                elif isinstance(act, Offload):
+                    self._exec_offload(act, plan.now)
+                elif isinstance(act, Discard):
+                    if act.replica is not None:
+                        # abort any copy still streaming this program's pages.
+                        # On program teardown the ledger already dropped the
+                        # records; on a live-program eviction (CPU/SSD overflow
+                        # passes) they are still open, and must be closed here —
+                        # a stale open offload would later match
+                        # _cancel_inflight_offload and cancel the wrong transfer
+                        if not self.sync_transfers:
+                            for job, rolled in self.planes[act.replica].abort_pid(
+                                act.pid, plan.now
+                            ):
+                                self.metrics.cancelled_pages += rolled
+                                if self.sched.ledger.is_open(job.action_id):
+                                    self.sched.ledger.cancel(job.action_id)
+                        # the logical SSD tier is backed by the host pool on the
+                        # real path — freeing it frees host pages
+                        tier = Tier.CPU if act.tier is Tier.SSD else act.tier
+                        self.engines[act.replica].discard_program(act.pid, tier)
+                elif isinstance(act, SetLabel):
+                    if act.replica is not None:
+                        self.engines[act.replica].set_label(act.pid, act.label)
+                elif isinstance(act, CancelTransfer):
+                    self._exec_cancel(act, plan.now)
+                elif isinstance(act, Migrate):
+                    self._exec_migrate(act, plan.now)
+            self.metrics.peak_inflight_bytes = max(
+                self.metrics.peak_inflight_bytes, self.sched.ledger.in_flight_bytes()
+            )
+            if self._checker is not None:
+                self._checker.check(plan.now)
 
     def _exec_forward(self, act: Forward, now: float) -> None:
         if act.source_tier in (Tier.CPU, Tier.SSD):
@@ -1052,10 +1062,6 @@ class MoriRouter:
             m.peak_live_slots = max(m.peak_live_slots, len(due))
             if len(due) >= 2:
                 m.multi_slot_steps += 1
-            if any(s.prefilling for s in slots.values()):
-                # the chunked-prefill payoff: decode kept running while a
-                # join was still mid-prefill on this replica
-                m.prefill_interleaved_steps += 1
             if busy:
                 m.overlap_decode_steps += 1
             for s in due:
@@ -1113,7 +1119,6 @@ class MoriRouter:
         wait = now - released
         if wait > _EPS:
             self.metrics.slot_wait_s += wait
-            self.metrics.slot_waits += 1
 
     def _quantize(self, t: float, end: float) -> float:
         """Snap a due time up to the pump quantum grid (when configured) so

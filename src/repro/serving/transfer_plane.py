@@ -57,6 +57,7 @@ import heapq
 import itertools
 from typing import Callable
 
+from repro.analysis.spans import HostTimes, region
 from repro.core.transfers import (
     CopyJob,
     CopyRequest,
@@ -425,6 +426,9 @@ class ReplicaTransferPlane:
         # monotone progress counter the router's stall guard reads: every
         # executed chunk tick counts, whether or not its job ever commits
         self.chunks_executed = 0
+        # serving-thread time in this plane (the router replaces this with
+        # its metrics)
+        self.host_times = HostTimes()
         self._heap: list[tuple[float, int, Callable[[float], None]]] = []
         self._seq = itertools.count()
         self.channels = TransferChannels(
@@ -443,9 +447,12 @@ class ReplicaTransferPlane:
     def advance(self, now: float) -> None:
         """Run every due chunk/completion, in eta order, stamping each with
         its own eta (not ``now``) so ledger acks carry faithful times."""
-        while self._heap and self._heap[0][0] <= now:
-            eta, _, fn = heapq.heappop(self._heap)
-            fn(eta)
+        if not (self._heap and self._heap[0][0] <= now):
+            return
+        with region("plane.advance", (self.host_times, "transfer_host_s")):
+            while self._heap and self._heap[0][0] <= now:
+                eta, _, fn = heapq.heappop(self._heap)
+                fn(eta)
 
     # ------------------------------------------------------------ admission
     def enqueue(self, act, now: float) -> None:

@@ -106,7 +106,6 @@ class AuditTarget:
     fn: object
     make_args: object
     donate_argnums: tuple = ()
-    static_argnums: tuple = ()
     bucket: dict = field(default_factory=dict)
     probe_args: object = None
 
@@ -495,7 +494,6 @@ def engine_targets(engine, *, prefill_chunks: bool = True) -> list[AuditTarget]:
                 fn=getattr(engine, s.fn_name),
                 make_args=s.make_args,
                 donate_argnums=s.donate_argnums,
-                static_argnums=s.static_argnums,
                 bucket=dict(s.bucket),
                 probe_args=probe,
             ))

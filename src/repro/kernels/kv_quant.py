@@ -76,76 +76,61 @@ def token_wire_bytes(layers: int, kv_heads: int, head_dim: int, fmt: str) -> int
 
 
 # ---------------------------------------------------------------- jnp side
+# A page is a lane-dense ``[T, F]`` tile (``F = KH * HD``: one token's heads
+# side by side), the pool's layout (``repro.serving.kvpool``).
 def quantize_pages(x):
     """Quantize pages to int8 with one scale per page (jit-safe).
 
-    ``x``: ``[..., T, KH, HD]`` — any number of leading axes (the pool uses
-    ``[L, N, T, KH, HD]``, the kernel's layer slice ``[N, T, KH, HD]``).
-    Returns ``(q int8 same-shape, scales f32 over the leading axes)``.
+    ``x``: ``[..., T, F]`` — any number of leading axes (the pool uses
+    ``[L, N, T, F]``). Returns ``(q int8 same-shape, scales f32 over the
+    leading axes)``.
     """
-    amax = jnp.max(jnp.abs(x.astype(F32)), axis=(-3, -2, -1))
+    amax = jnp.max(jnp.abs(x.astype(F32)), axis=(-2, -1))
     scales = jnp.maximum(amax, SCALE_EPS) / QMAX
-    q = jnp.round(x.astype(F32) / scales[..., None, None, None])
+    q = jnp.round(x.astype(F32) / scales[..., None, None])
     q = jnp.clip(q, -QMAX, QMAX).astype(jnp.int8)
     return q, scales
 
 
 def dequantize_pages(q, scales, dtype=jnp.bfloat16):
     """Inverse of :func:`quantize_pages` (up to quantization error)."""
-    return (q.astype(F32) * scales[..., None, None, None]).astype(dtype)
+    return (q.astype(F32) * scales[..., None, None]).astype(dtype)
 
 
-def requantize_insert(q_pages, scales, pages, offsets, new_vals):
-    """Insert one new token per batch row into quantized pages (jit-safe).
+def requantize_insert(q_pages, scales, layer, pages, offsets, new_vals):
+    """Insert one new token per batch row into one layer's quantized pages
+    (jit-safe; an in-place update when ``q_pages`` is a donated argument or
+    a loop carry).
 
     The decode append on an int8-resident pool: dequantize the ``B``
-    affected pages, write ``new_vals[b]`` at ``(pages[b], offsets[b])``,
-    re-derive each page's scale (it may grow — the new token can exceed the
-    old amax) and requantize. Only the touched pages move; the pool update
-    is a single scatter.
+    affected pages of ``layer``, write ``new_vals[b]`` at ``(pages[b],
+    offsets[b])``, re-derive each page's scale (it may grow — the new token
+    can exceed the old amax) and requantize. Only the touched pages move.
 
-    ``q_pages`` ``[N, T, KH, HD]`` int8, ``scales`` ``[N]`` f32,
-    ``pages``/``offsets`` ``[B]`` int32 (distinct pages — each batch row
-    owns its tail page), ``new_vals`` ``[B, KH, HD]``.
+    ``q_pages`` ``[L, N, T, F]`` int8, ``scales`` ``[L, N]`` f32, ``layer``
+    a scalar, ``pages``/``offsets`` ``[B]`` int32 (distinct pages — each
+    batch row owns its tail page), ``new_vals`` ``[B, F]``.
     """
     B = pages.shape[0]
-    tiles = q_pages[pages].astype(F32) * scales[pages][:, None, None, None]
+    tiles = q_pages[layer, pages].astype(F32) * scales[layer, pages][:, None, None]
     tiles = tiles.at[jnp.arange(B), offsets].set(new_vals.astype(F32))
-    amax = jnp.max(jnp.abs(tiles), axis=(1, 2, 3))
-    new_s = jnp.maximum(amax, SCALE_EPS) / QMAX
-    q = jnp.round(tiles / new_s[:, None, None, None])
-    q = jnp.clip(q, -QMAX, QMAX).astype(jnp.int8)
-    return q_pages.at[pages].set(q), scales.at[pages].set(new_s)
-
-
-def requantize_insert_run(q_k, s_k, pages, offsets, new_vals):
-    """All-layers twin of :func:`requantize_insert` for the pool layout:
-    ``q_k`` ``[L, N, T, KH, HD]`` int8, ``s_k`` ``[L, N]`` f32, ``new_vals``
-    ``[L, B, KH, HD]`` — one batched gather/scatter commits every layer's
-    append (the paged decode step's post-scan commit)."""
-    B = pages.shape[0]
-    tiles = q_k[:, pages].astype(F32) * s_k[:, pages][..., None, None, None]
-    tiles = tiles.at[:, jnp.arange(B), offsets].set(new_vals.astype(F32))
-    amax = jnp.max(jnp.abs(tiles), axis=(2, 3, 4))
-    new_s = jnp.maximum(amax, SCALE_EPS) / QMAX
-    q = jnp.round(tiles / new_s[..., None, None, None])
-    q = jnp.clip(q, -QMAX, QMAX).astype(jnp.int8)
-    return q_k.at[:, pages].set(q), s_k.at[:, pages].set(new_s)
+    q, new_s = quantize_pages(tiles)
+    return q_pages.at[layer, pages].set(q), scales.at[layer, pages].set(new_s)
 
 
 # -------------------------------------------------------------- numpy side
 def quantize_np(x: np.ndarray):
-    """Host-staging quantizer: ``x`` ``[L, T, KH, HD]`` (one page, all
-    layers) → ``(int8 payload, f32 scales [L])``. Mirrors
-    :func:`quantize_pages` exactly so device- and host-side quantization of
-    the same page produce identical bytes."""
+    """Host-staging quantizer: ``x`` ``[L, T, F]`` (one page, all layers)
+    → ``(int8 payload, f32 scales [L])``. Mirrors :func:`quantize_pages`
+    exactly so device- and host-side quantization of the same page produce
+    identical bytes."""
     xf = np.asarray(x, np.float32)
-    amax = np.max(np.abs(xf), axis=(1, 2, 3))
+    amax = np.max(np.abs(xf), axis=(1, 2))
     scales = (np.maximum(amax, SCALE_EPS) / QMAX).astype(np.float32)
-    q = np.rint(xf / scales[:, None, None, None])
+    q = np.rint(xf / scales[:, None, None])
     return np.clip(q, -QMAX, QMAX).astype(np.int8), scales
 
 
 def dequantize_np(q: np.ndarray, scales: np.ndarray) -> np.ndarray:
-    """Inverse of :func:`quantize_np` → float32 ``[L, T, KH, HD]``."""
-    return q.astype(np.float32) * np.asarray(scales, np.float32)[:, None, None, None]
+    """Inverse of :func:`quantize_np` → float32 ``[L, T, F]``."""
+    return q.astype(np.float32) * np.asarray(scales, np.float32)[:, None, None]

@@ -23,51 +23,32 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
-def _gather_pages(k_pages, v_pages, block_tables, k_scales, v_scales):
-    """Dense gather -> ``[B, P*T, KH, D]``, dequantizing int8 pools."""
+def _gather_pages(q, k_pages, v_pages, block_tables, k_scales, v_scales,
+                  layer):
+    """Dense gather of one layer -> ``[B, P*T, KH, D]``, dequantizing int8
+    pools. The pool's lane-dense ``[T, KH*D]`` page tiles are reshaped to
+    per-head ``[T, KH, D]`` here (``D`` from ``q``)."""
     B, P = block_tables.shape
-    T, KH, D = k_pages.shape[1:]
-    k = k_pages[block_tables]                       # [B, P, T, KH, D]
-    v = v_pages[block_tables]
+    T, F = k_pages.shape[2:]
+    D = q.shape[-1]
+    k = k_pages[layer, block_tables]                # [B, P, T, F]
+    v = v_pages[layer, block_tables]
     if k_scales is not None:
-        k = k.astype(F32) * k_scales[block_tables][..., None, None, None]
-        v = v.astype(F32) * v_scales[block_tables][..., None, None, None]
-    return k.reshape(B, P * T, KH, D), v.reshape(B, P * T, KH, D)
+        k = k.astype(F32) * k_scales[layer, block_tables][..., None, None]
+        v = v.astype(F32) * v_scales[layer, block_tables][..., None, None]
+    shape = (B, P * T, F // D, D)
+    return k.reshape(shape), v.reshape(shape)
 
 
 def paged_attention_ref(
     q, k_pages, v_pages, block_tables, lengths,
-    k_scales=None, v_scales=None, *, softcap=None, window=None,
+    k_scales=None, v_scales=None, *, layer=0, softcap=None, window=None,
 ):
-    k, v = _gather_pages(k_pages, v_pages, block_tables, k_scales, v_scales)
-    return _gathered_attention(q, k, v, lengths, softcap, window)
-
-
-def paged_attention_decode_ref(
-    q, k_new, v_new, k_pages, v_pages, block_tables, lengths,
-    k_scales=None, v_scales=None, *, softcap=None, window=None,
-):
-    """Decode-step oracle where the current token's KV (``k_new``/``v_new``
-    ``[B, KH, D]``, global position ``lengths - 1``) has *not* been written
-    to the pool yet: it is inserted into the gathered context locally.
-
-    Bit-identical to scattering into the tail page first and calling
-    :func:`paged_attention_ref` — but the insert touches a ``[B, P*T]``
-    gather, not the ``[N, T]`` pool, so a layer scan over this op never
-    copies the pool. The engine appends all layers' KV to the tail pages
-    in one batched scatter after the scan.
-
-    On an int8 pool the insert lands in the dequantized f32 gather, i.e.
-    the new token is attended at full precision; the kernel path instead
-    requantizes the tail page before the gather, which adds one page's
-    quantization error on the freshly appended token (inside the
-    documented parity band, pinned in tests/test_kv_quant.py).
-    """
-    B = q.shape[0]
-    k, v = _gather_pages(k_pages, v_pages, block_tables, k_scales, v_scales)
-    idx = jnp.arange(B), lengths - 1
-    k = k.at[idx].set(k_new.astype(k.dtype))
-    v = v.at[idx].set(v_new.astype(v.dtype))
+    """``k_pages``/``v_pages`` ``[L, N, T, KH*D]`` (``k_scales``/``v_scales``
+    ``[L, N]`` on an int8 pool); attends over ``layer``'s pages."""
+    k, v = _gather_pages(
+        q, k_pages, v_pages, block_tables, k_scales, v_scales, layer
+    )
     return _gathered_attention(q, k, v, lengths, softcap, window)
 
 

@@ -449,25 +449,23 @@ def _write_slot(cache, new, idx):
 
 
 def apply_dense_block_paged(
-    p, x, cfg: ModelConfig, *, k_pages, v_pages, block_tables, tail_pages,
-    tail_offsets, lengths, k_scales=None, v_scales=None, window=None,
-    ctx=NULL_CTX,
+    p, x, cfg: ModelConfig, *, layer, k_pages, v_pages, block_tables,
+    tail_pages, tail_offsets, lengths, k_scales=None, v_scales=None,
+    window=None, ctx=NULL_CTX,
 ):
     """Decode mode of :func:`apply_dense_block` over a *paged* KV pool.
 
     The block-table twin of the dense-slot decode branch: instead of a
-    ``[B, S_max, KH, HD]`` slot cache it takes one layer's slice of the
-    ``PagePool`` (``k_pages``/``v_pages`` ``[N, T, KH, HD]``) *read-only*
-    and attends through ``block_tables`` ``[B, P]`` with the
-    paged-attention kernel (GQA + softcap + sliding window). The new
-    token's KV (global position ``lengths[b] - 1``, destined for
-    ``(tail_pages[b], tail_offsets[b])``) is incorporated by the kernel
-    dispatch itself; it is *returned*, not written — the caller commits
-    every layer's append to the pool in one batched scatter after the
-    layer scan, so scanning this block never copies the pool per layer.
-    On an int8-resident pool ``k_scales``/``v_scales`` carry this layer's
-    per-page dequant sidecar (``[N]``), threaded to the kernel dispatch.
-    Returns ``(x', (k_new, v_new), aux)`` with k_new/v_new ``[B, KH, HD]``.
+    ``[B, S_max, KH, HD]`` slot cache it takes the whole ``PagePool``
+    (``k_pages``/``v_pages`` ``[L, N, T, KH*HD]``, the layer scan's carry)
+    and ``layer``, its own index in it. The new token's KV (global position
+    ``lengths[b] - 1``) is written at ``(layer, tail_pages[b],
+    tail_offsets[b])`` in place, then the token attends through
+    ``block_tables`` ``[B, P]`` with the paged-attention kernel (GQA +
+    softcap + sliding window). On an int8-resident pool
+    ``k_scales``/``v_scales`` (``[L, N]``) ride along and are updated with
+    the write. Returns ``(x', (k_pages', v_pages', k_scales', v_scales'),
+    aux)``.
     """
     h_, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     B = x.shape[0]
@@ -476,9 +474,9 @@ def apply_dense_block_paged(
     q, k, v = _project_qkv(
         p["attn"], a_in, h_, kh, hd, positions, cfg.rope_theta, ctx=ctx
     )
-    attn = paged_attention_decode(
+    attn, *pool = paged_attention_decode(
         q[:, 0], k[:, 0], v[:, 0], k_pages, v_pages, block_tables, lengths,
-        tail_pages, tail_offsets, k_scales, v_scales,
+        tail_pages, tail_offsets, k_scales, v_scales, layer=layer,
         softcap=cfg.attn_logit_softcap, window=window,
     )                                                      # [B, H, D]
     x = x + (attn.reshape(B, 1, h_ * hd) @ p["attn"]["wo"])
@@ -489,7 +487,7 @@ def apply_dense_block_paged(
         f_out, aux = apply_ffn(p["ffn"], f_in, ctx), 0.0
     x = x + f_out
     x = ctx.constrain(x, ("batch", "seq", "embed_act"))
-    return x, (k[:, 0], v[:, 0]), aux
+    return x, tuple(pool), aux
 
 
 # ============================================================== mamba2 block

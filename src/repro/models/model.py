@@ -158,13 +158,11 @@ class Model:
 
     # ------------------------------------------------------- sequence mode
     def sequence(self, params, x, positions, ctx=NULL_CTX, collect_cache=False,
-                 frames=None, prefix=None, prefix_valid=None):
+                 frames=None, prefix=None):
         """Run the full stack over a token-embedded sequence ``x`` [B,S,d].
 
         ``prefix``: optional {"k","v"} [L,B,Sp,KH,HD] radix-cached KV for
-        chunked prefill (dense families only). ``prefix_valid`` (traced
-        scalar) marks how many prefix positions are real when the prefix
-        is padded to a bucket for shape-stable jit. Returns
+        suffix prefill (dense families only). Returns
         (hidden, cache_tree_or_None, aux_loss).
         """
         cfg = self.cfg
@@ -178,7 +176,7 @@ class Model:
                 p, pre = xs
                 h, kv, aux = apply_dense_block(
                     p, h, cfg, positions=positions, window=cfg.sliding_window,
-                    prefix=pre, prefix_valid=prefix_valid, ctx=ctx,
+                    prefix=pre, ctx=ctx,
                 )
                 return h, (kv if collect_cache else None, aux)
 
@@ -442,21 +440,19 @@ class Model:
 
         The paged twin of :meth:`decode`: the KV cache is the serving
         engine's ``PagePool`` arrays ``k_pages``/``v_pages``
-        ``[L, N, T, KH, HD]`` — not a per-slot dense buffer — and each
+        ``[L, N, T, KH*HD]`` — not a per-slot dense buffer — and each
         sequence reads its context through ``block_tables`` ``[B, P]``.
-        The new token's KV (global position ``lengths[b] - 1``) is carried
-        out of the layer scan and appended at ``(tail_pages[b],
-        tail_offsets[b])`` in ONE batched scatter for all layers — with
-        input donation that is an in-place pool update, so a decode step
-        never copies the pool (the old per-layer write forced L full-pool
-        copies through the scan). Layers scan exactly like :meth:`decode`
-        so compile stays O(1) in depth.
+        The pool is the layer scan's *carry*: layer ``l`` writes the new
+        token's KV (global position ``lengths[b] - 1``) at ``(l,
+        tail_pages[b], tail_offsets[b])`` in place and its attention reads
+        the carried pool at ``l``, so with input donation a decode step
+        copies neither the pool nor a layer slice of it. Layers scan
+        exactly like :meth:`decode` so compile stays O(1) in depth.
 
         On an int8-resident pool, pass the per-(layer, page) scale
-        sidecars ``k_scales``/``v_scales`` ``[L, N]``: each layer's slice
-        rides the scan for the kernel's dequant, and the commit becomes a
-        requantize-insert of the tail pages (their scales may grow to
-        admit the new token).
+        sidecars ``k_scales``/``v_scales`` ``[L, N]``: they ride the carry
+        too, and each layer's write becomes a requantize-insert of its
+        tail pages (their scales may grow to admit the new token).
         Returns ``(logits [B, V], k_pages', v_pages')`` — plus
         ``(k_scales', v_scales')`` when sidecars were passed.
         """
@@ -464,75 +460,129 @@ class Model:
         assert cfg.family in ("dense", "moe", "vlm") and (
             not cfg.local_global_alternating
         ), "paged decode serves the dense-cache families"
-        quantized = k_scales is not None
         x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]  # [B,1,d]
 
-        def body(h, xs):
-            if quantized:
-                p, kp, vp, ks, vs = xs
-            else:
-                p, kp, vp = xs
-                ks = vs = None
-            h, (k_new, v_new), _ = apply_dense_block_paged(
-                p, h, cfg, k_pages=kp, v_pages=vp, block_tables=block_tables,
-                tail_pages=tail_pages, tail_offsets=tail_offsets,
-                lengths=lengths, k_scales=ks, v_scales=vs,
-                window=cfg.sliding_window, ctx=ctx,
+        def body(carry, xs):
+            h, kp, vp, ks, vs = carry
+            p, layer = xs
+            h, pool, _ = apply_dense_block_paged(
+                p, h, cfg, layer=layer, k_pages=kp, v_pages=vp,
+                block_tables=block_tables, tail_pages=tail_pages,
+                tail_offsets=tail_offsets, lengths=lengths, k_scales=ks,
+                v_scales=vs, window=cfg.sliding_window, ctx=ctx,
             )
-            return h, (k_new, v_new)
+            return (h, *pool), None
 
-        xs = (
-            (params["blocks"], k_pages, v_pages, k_scales, v_scales)
-            if quantized
-            else (params["blocks"], k_pages, v_pages)
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, k_pages, v_pages, k_scales, v_scales), _ = jax.lax.scan(
+            body, (x, k_pages, v_pages, k_scales, v_scales),
+            (params["blocks"], layers),
         )
-        x, (k_news, v_news) = jax.lax.scan(body, x, xs)
-        # commit all layers' appends at once: k_news/v_news [L, B, KH, HD]
-        # land at [:, tail_pages[b], tail_offsets[b]] (unique per row)
-        if quantized:
-            k_pages, k_scales = kv_quant.requantize_insert_run(
-                k_pages, k_scales, tail_pages, tail_offsets, k_news
-            )
-            v_pages, v_scales = kv_quant.requantize_insert_run(
-                v_pages, v_scales, tail_pages, tail_offsets, v_news
-            )
-        else:
-            k_pages = k_pages.at[:, tail_pages, tail_offsets].set(
-                k_news.astype(k_pages.dtype)
-            )
-            v_pages = v_pages.at[:, tail_pages, tail_offsets].set(
-                v_news.astype(v_pages.dtype)
-            )
         h = rmsnorm(x[:, 0, :], params["ln_f"])
         logits = softcap((h @ params["head"]).astype(F32), cfg.final_logit_softcap)
         logits = ctx.constrain(logits, ("batch", "vocab_act"))
+        if k_scales is not None:
+            return logits, k_pages, v_pages, k_scales, v_scales
+        return logits, k_pages, v_pages
+
+    def prefill_paged(
+        self, params, k_pages, v_pages, tokens, prefix_idx, write_idx,
+        prefix_valid, pos0, take, logit_index, k_scales=None, v_scales=None,
+        ctx=NULL_CTX,
+    ):
+        """One chunk of prefill into a paged pool (dense-cache families).
+
+        The paged twin of :meth:`prefill` with a radix-cached prefix: the
+        pool ``[L, N, T, KH*HD]`` is the layer scan's carry. Layer ``l``
+        gathers its prefix pages ``pool[l, prefix_idx]`` (padded to a page
+        bucket; positions from ``prefix_valid`` on are masked), attends
+        the chunk ``tokens`` ``[1, C]`` (absolute positions from ``pos0``)
+        over prefix + chunk, and writes the chunk's KV into its pages
+        ``pool[l, write_idx]`` in place. Chunk positions from ``take`` on
+        are written as zeros, so a partial tail page holds what a whole
+        prefill would leave there. Nothing pool- or layer-sized is copied.
+
+        On an int8-resident pool the gather dequantizes through the scale
+        sidecars ``k_scales``/``v_scales`` ``[L, N]`` and each written page
+        is quantized with a fresh scale. Returns ``(logits [1, V],
+        k_pages', v_pages')`` — plus ``(k_scales', v_scales')`` when
+        sidecars were passed.
+        """
+        cfg = self.cfg
+        assert cfg.family in ("dense", "moe", "vlm") and (
+            not cfg.local_global_alternating
+        ), "paged prefill serves the dense-cache families"
+        x = jnp.take(params["embed"], tokens, axis=0)               # [1,C,d]
+        C = x.shape[1]
+        positions = pos0 + jnp.arange(C)[None, :]
+        x = ctx.constrain(x, ("batch", "seq", "embed_act"))
+        kh, hd = cfg.num_kv_heads, cfg.head_dim
+        T = k_pages.shape[2]
+        n_write = write_idx.shape[0]
+        keep = (jnp.arange(n_write * T) < take)[:, None]
+        quantized = k_scales is not None
+
+        def gather(pages, scales, layer):
+            got = pages[layer, prefix_idx]                          # [n,T,F]
+            if quantized:
+                got = kv_quant.dequantize_pages(
+                    got, scales[layer, prefix_idx], jnp.bfloat16
+                )
+            return got.reshape(1, -1, kh, hd)
+
+        def write(pages, scales, layer, kv):
+            kv = kv[0].reshape(C, kh * hd)
+            kv = jnp.pad(kv, ((0, n_write * T - C), (0, 0)))
+            kv = jnp.where(keep, kv, 0).reshape(n_write, T, kh * hd)
+            if quantized:
+                kv, s = kv_quant.quantize_pages(kv)
+                scales = scales.at[layer, write_idx].set(s)
+            return pages.at[layer, write_idx].set(kv.astype(pages.dtype)), scales
+
+        def body(carry, xs):
+            h, kp, vp, ks, vs = carry
+            p, layer = xs
+            prefix, valid = None, None
+            # shape branch is deliberate bucketing: prefix_idx is padded to
+            # the table bucket, so this compiles once per bucket
+            if prefix_idx.shape[0]:  # lint: jit-shape-branch-ok
+                prefix = (gather(kp, ks, layer), gather(vp, vs, layer))
+                valid = prefix_valid
+            h, (k, v), _ = apply_dense_block(
+                p, h, cfg, positions=positions, window=cfg.sliding_window,
+                prefix=prefix, prefix_valid=valid, ctx=ctx,
+            )
+            kp, ks = write(kp, ks, layer, k)
+            vp, vs = write(vp, vs, layer, v)
+            return (h, kp, vp, ks, vs), None
+
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, k_pages, v_pages, k_scales, v_scales), _ = jax.lax.scan(
+            body, (x, k_pages, v_pages, k_scales, v_scales),
+            (params["blocks"], layers),
+        )
+        h = rmsnorm(x[:, logit_index, :], params["ln_f"])
+        logits = softcap((h @ params["head"]).astype(F32), cfg.final_logit_softcap)
         if quantized:
             return logits, k_pages, v_pages, k_scales, v_scales
         return logits, k_pages, v_pages
 
     # ------------------------------------------------------------ prefill
     def prefill(self, params, batch: dict, ctx=NULL_CTX, prefix=None,
-                logit_index=None, positions_offset=None, prefix_valid=None):
+                logit_index=None):
         """Full- or suffix-context forward; returns (last_logits, cache).
 
-        With ``prefix`` (stacked radix-cached KV), this is chunked prefill:
-        only ``batch["tokens"]`` (the suffix) is computed, attending over
-        prefix+suffix. The returned cache covers the suffix only.
+        With ``prefix`` (stacked radix-cached KV), only ``batch["tokens"]``
+        (the suffix) is computed, attending over prefix+suffix. The
+        returned cache covers the suffix only. (The dense-slot engine
+        prefills this way; the paged engine prefills into its pool through
+        :meth:`prefill_paged`.)
 
         ``logit_index`` names the *token* position whose logits to return
-        (default: the last; may be a traced scalar — the engine's jitted
-        chunk prefill passes it as an argument so the final-chunk shape
-        compiles once). The serving engine pads suffixes to a fixed bucket
-        so prefill compiles once per bucket instead of once per length —
-        causality guarantees positions at or before ``logit_index`` never
-        see the padding.
-
-        ``positions_offset``/``prefix_valid`` support a *bucketed* prefix:
-        when the prefix KV is padded past its real length for shape-stable
-        jit, ``positions_offset`` is the real absolute position of the
-        first suffix token (RoPE must use true positions, not padded
-        indices) and ``prefix_valid`` masks the padded prefix tail out of
-        attention. Both default to the unpadded behaviour.
+        (default: the last). The serving engine pads suffixes to a fixed
+        bucket so prefill compiles once per bucket instead of once per
+        length — causality guarantees positions at or before
+        ``logit_index`` never see the padding.
         """
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -544,13 +594,11 @@ class Model:
             x = jnp.concatenate([img, x], axis=1)
         S = x.shape[1]
         q_off = 0 if prefix is None else prefix["k"].shape[2]
-        pos0 = q_off if positions_offset is None else positions_offset
-        positions = pos0 + jnp.arange(S)[None, :]
+        positions = q_off + jnp.arange(S)[None, :]
         x = ctx.constrain(x, ("batch", "seq", "embed_act"))
         h, cache, _ = self.sequence(
             params, x, positions, ctx, collect_cache=True,
             frames=batch.get("frames"), prefix=prefix,
-            prefix_valid=prefix_valid,
         )
         idx = -1 if logit_index is None else n_img + logit_index
         h = rmsnorm(h[:, idx, :], params["ln_f"])

@@ -9,8 +9,9 @@ This is the execution plane the MORI scheduler drives in the real system:
 * **block-table decode** (default): the pool *is* the decode state.
   Continuous-batching decode runs the paged-attention kernel straight off
   the ``PagePool`` through per-slot block tables; each step appends the
-  new token's KV into the slot's tail page in place. ``submit()`` writes
-  suffix prefill KV directly into pool pages (cached prefix pages are
+  new token's KV into the slot's tail page in place. Prefill (``submit()``,
+  or ``begin_submit()`` + ``prefill_step()`` in budgeted chunks) writes
+  suffix KV directly into pool pages (cached prefix pages are
   *referenced*, never copied) and ``_finish`` hands the already-resident
   full pages to the radix tree — the dense-slot path's
   gather → concatenate → slot-write → write-back round trip is gone,
@@ -117,7 +118,6 @@ class WarmupSpec:
     fn_name: str                 # engine attribute holding the jitted fn
     make_args: object            # () -> positional argument tuple
     donate_argnums: tuple = ()
-    static_argnums: tuple = ()
     bucket: dict = field(default_factory=dict)
     probe_group: str = ""
 
@@ -141,62 +141,29 @@ def greedy_token(logits):
 
 def _chunk_prefill_impl(model, ctx, params, k_pages, v_pages, prefix_idx,
                         write_idx, tokens, prefix_valid, pos0, take,
-                        logit_idx, page_tokens):
-    """One chunk of prefill, pool-in/pool-out (jit body; donation makes the
-    page scatter an in-place pool update). ``prefix_idx`` is padded to a
-    page bucket (garbage tail masked via ``prefix_valid``); ``write_idx``
-    is padded with a scratch page; chunk KV past ``take`` is zeroed so the
-    written tail page is byte-identical to the monolithic path's."""
-    from repro.serving.kvpool import gather_token_run, scatter_token_run
-
-    prefix = None
-    # shape branch is deliberate bucketing: prefix_idx is padded to the
-    # table bucket, so this compiles once per bucket, not per length
-    if prefix_idx.shape[0]:  # lint: jit-shape-branch-ok
-        pk, pv = gather_token_run(k_pages, v_pages, prefix_idx)
-        prefix = {"k": pk[:, None], "v": pv[:, None]}           # [L,1,Sp,KH,HD]
-    logits, cache = model.prefill(
-        params, {"tokens": tokens}, ctx=ctx, prefix=prefix,
-        logit_index=logit_idx, positions_offset=pos0,
-        prefix_valid=prefix_valid if prefix is not None else None,
-    )
-    k_c = cache["k"][:, 0]                                     # [L,C_pad,KH,HD]
-    v_c = cache["v"][:, 0]
-    keep = (jnp.arange(k_c.shape[1]) < take)[None, :, None, None]
-    k_c = jnp.where(keep, k_c, 0)
-    v_c = jnp.where(keep, v_c, 0)
-    k_pages, v_pages = scatter_token_run(
-        k_pages, v_pages, write_idx, k_c, v_c, page_tokens
+                        logit_idx):
+    """One chunk of prefill, pool-in/pool-out (jit body; the pool is
+    donated and carried through the layer scan, so each layer's page
+    writes are in place). ``prefix_idx`` is padded to a page bucket
+    (garbage tail masked via ``prefix_valid``); ``write_idx`` is padded
+    with a scratch page; chunk KV past ``take`` is zeroed so the written
+    tail page holds the same bytes however the suffix was chunked."""
+    logits, k_pages, v_pages = model.prefill_paged(
+        params, k_pages, v_pages, tokens, prefix_idx, write_idx,
+        prefix_valid, pos0, take, logit_idx, ctx=ctx,
     )
     return logits[0], k_pages, v_pages
 
 
 def _chunk_prefill_impl_q(model, ctx, params, k_pages, v_pages, k_scale,
                           v_scale, prefix_idx, write_idx, tokens,
-                          prefix_valid, pos0, take, logit_idx, page_tokens):
+                          prefix_valid, pos0, take, logit_idx):
     """Int8-resident twin of :func:`_chunk_prefill_impl`: the prefix gather
-    dequantizes through the scale sidecars and the chunk scatter quantizes
+    dequantizes through the scale sidecars and the chunk write quantizes
     each written page (payload + sidecar updated together, all donated)."""
-    from repro.serving.kvpool import gather_token_run_q, scatter_token_run_q
-
-    prefix = None
-    if prefix_idx.shape[0]:  # lint: jit-shape-branch-ok
-        pk, pv = gather_token_run_q(
-            k_pages, k_scale, v_pages, v_scale, prefix_idx, jnp.bfloat16
-        )
-        prefix = {"k": pk[:, None], "v": pv[:, None]}           # [L,1,Sp,KH,HD]
-    logits, cache = model.prefill(
-        params, {"tokens": tokens}, ctx=ctx, prefix=prefix,
-        logit_index=logit_idx, positions_offset=pos0,
-        prefix_valid=prefix_valid if prefix is not None else None,
-    )
-    k_c = cache["k"][:, 0]                                     # [L,C_pad,KH,HD]
-    v_c = cache["v"][:, 0]
-    keep = (jnp.arange(k_c.shape[1]) < take)[None, :, None, None]
-    k_c = jnp.where(keep, k_c, 0)
-    v_c = jnp.where(keep, v_c, 0)
-    k_pages, k_scale, v_pages, v_scale = scatter_token_run_q(
-        k_pages, k_scale, v_pages, v_scale, write_idx, k_c, v_c, page_tokens
+    logits, k_pages, v_pages, k_scale, v_scale = model.prefill_paged(
+        params, k_pages, v_pages, tokens, prefix_idx, write_idx,
+        prefix_valid, pos0, take, logit_idx, k_scale, v_scale, ctx=ctx,
     )
     return logits[0], k_pages, v_pages, k_scale, v_scale
 
@@ -246,8 +213,8 @@ def _abstract_weights_and_pool(cfg: ModelConfig, n_pages: int,
     params = jax.tree.map(
         lambda l: sds(l.shape, l.dtype), Model(cfg).describe(), is_leaf=is_leaf
     )
-    pool = sds((cfg.num_layers, n_pages, page_tokens, cfg.num_kv_heads,
-                cfg.head_dim), jnp.bfloat16)
+    pool = sds((cfg.num_layers, n_pages, page_tokens,
+                cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16)
     return params, pool, sds
 
 
@@ -276,7 +243,7 @@ def chunk_step_args(cfg: ModelConfig, *, n_pages: int, page_tokens: int,
     scalar = sds(())
     return (params, pool, pool, sds((prefix_pages,)),
             sds((-(-chunk_tokens // page_tokens),)), sds((1, chunk_tokens)),
-            scalar, scalar, scalar, scalar, page_tokens)
+            scalar, scalar, scalar, scalar)
 
 
 @functools.lru_cache(maxsize=None)
@@ -290,9 +257,9 @@ def _chunk_prefill_fn(cfg: ModelConfig, quantized: bool = False):
     model = Model(cfg)
     if quantized:
         fn = functools.partial(_chunk_prefill_impl_q, model, NULL_CTX)
-        return jax.jit(fn, donate_argnums=(1, 2, 3, 4), static_argnums=(12,))
+        return jax.jit(fn, donate_argnums=(1, 2, 3, 4))
     fn = functools.partial(_chunk_prefill_impl, model, NULL_CTX)
-    return jax.jit(fn, donate_argnums=(1, 2), static_argnums=(10,))
+    return jax.jit(fn, donate_argnums=(1, 2))
 
 
 #: per-process engine ids for compile-tracker names (stable within a run)
@@ -441,12 +408,12 @@ class Engine:
             elif self.quantized:
                 self._chunk_fn = jax.jit(
                     functools.partial(_chunk_prefill_impl_q, self.model, self.ctx),
-                    donate_argnums=(1, 2, 3, 4), static_argnums=(12,),
+                    donate_argnums=(1, 2, 3, 4),
                 )
             else:
                 self._chunk_fn = jax.jit(
                     functools.partial(_chunk_prefill_impl, self.model, self.ctx),
-                    donate_argnums=(1, 2), static_argnums=(10,),
+                    donate_argnums=(1, 2),
                 )
         # metrics
         self.steps = 0
@@ -569,7 +536,6 @@ class Engine:
         sp = int(scratch[0])
 
         chunk_donate = (1, 2, 3, 4) if quantized else (1, 2)
-        chunk_static = (12,) if quantized else (10,)
 
         def chunk_args(p_pad: int, c_pad: int):
             def make():
@@ -584,7 +550,7 @@ class Engine:
                     jnp.asarray([sp] * w_pad, jnp.int32),
                     jnp.zeros((1, c_pad), jnp.int32),
                     jnp.int32(0), jnp.int32(0),
-                    jnp.int32(c_pad), jnp.int32(c_pad - 1), T,
+                    jnp.int32(c_pad), jnp.int32(c_pad - 1),
                 )
 
             return make
@@ -602,7 +568,7 @@ class Engine:
                          f"chunk={c_pad}]",
                     kind="chunk_prefill", fn_name="_chunk_fn",
                     make_args=chunk_args(p_pad, c_pad),
-                    donate_argnums=chunk_donate, static_argnums=chunk_static,
+                    donate_argnums=chunk_donate,
                     bucket={"prefix_pages": p_pad, "chunk_tokens": c_pad},
                     probe_group=(
                         f"engine{self._audit_id}/chunk_prefill/{group}"
@@ -645,30 +611,33 @@ class Engine:
             )
 
     def submit(self, req: EngineRequest) -> int:
-        """Admit one request: radix match -> reload -> chunked prefill."""
+        """Admit one request whole: radix match -> reload -> prefill.
+
+        On the paged engine this is :meth:`begin_submit` and one
+        :meth:`prefill_step` over the whole suffix, so every prefill into
+        the pool runs the same compiled chunk step. The dense-slot engine
+        prefills the suffix over the gathered cached prefix and copies the
+        context into its slot."""
+        if not self.dense_slots:
+            job = self.begin_submit(req)
+            T = self.page_tokens
+            done = self.prefill_step(job, -(-len(job.suffix) // T) * T)
+            assert done, "a whole-suffix budget prefills in one chunk"
+            return job.slot_id
         assert self._free_slots, "no free decode slots"
         assert len(req.tokens) + req.max_new_tokens <= self.max_seq
         pid = req.program_id
         self._san_scope(f"submit:{pid}")
 
         with region("engine.admit", (self.host_times, "prefill_host_s"), pid=pid):
-            # 1. promote any host-resident prefix pages back to the device
             reloaded = self._reload_prefix(req.tokens)
-            # 2. device-resident prefix
             nodes = self.tree.match_prefix(req.tokens)
             cached = len(nodes) * self.page_tokens
             pages = [n.device_page for n in nodes]
             suffix = req.tokens[cached:]
             assert suffix, "request must extend its cached prefix"
-
-            # pin before touching the pool: suffix-page allocation below may
-            # evict, and the prefix chain a block table points at must survive.
-            # tree.pin covers the program's own nodes; the matched chain is
-            # refcount-held separately because a shared prefix may belong to a
-            # different program (released in _finish)
+            # the program's own nodes stay pinned until _finish
             self.tree.pin(pid)
-            if not self.dense_slots:
-                self.tree.acquire_nodes(nodes)
 
             prefix = None
             if pages:
@@ -684,10 +653,16 @@ class Engine:
             with region("engine.chunk_wait", pid=pid):
                 first_token = int(greedy_token(logits[0]))
 
-            # 3. install into a decode slot
             sid = self._free_slots.pop()
             length = len(req.tokens)
-            slot = _Slot(
+            k_ctx = cache["k"][:, 0, : len(suffix)]                 # [L,Ssuf,KH,HD]
+            v_ctx = cache["v"][:, 0, : len(suffix)]
+            if prefix is not None:
+                k_ctx = jnp.concatenate([prefix["k"][:, 0], k_ctx], axis=1)
+                v_ctx = jnp.concatenate([prefix["v"][:, 0], v_ctx], axis=1)
+            self.slot_k = self.slot_k.at[:, sid, :length].set(k_ctx)
+            self.slot_v = self.slot_v.at[:, sid, :length].set(v_ctx)
+            self.slots[sid] = _Slot(
                 request=req,
                 slot_id=sid,
                 length=length,
@@ -696,40 +671,9 @@ class Engine:
                 prefilled_tokens=len(suffix),
                 reloaded_pages=reloaded,
             )
-            k_suf = cache["k"][:, 0, : len(suffix)]                 # [L,Ssuf,KH,HD]
-            v_suf = cache["v"][:, 0, : len(suffix)]
-            if self.dense_slots:
-                k_ctx, v_ctx = k_suf, v_suf
-                if prefix is not None:
-                    k_ctx = jnp.concatenate([prefix["k"][:, 0], k_ctx], axis=1)
-                    v_ctx = jnp.concatenate([prefix["v"][:, 0], v_ctx], axis=1)
-                self.slot_k = self.slot_k.at[:, sid, :length].set(k_ctx)
-                self.slot_v = self.slot_v.at[:, sid, :length].set(v_ctx)
-            else:
-                # block-table install: reference the cached prefix pages and
-                # write the suffix KV straight into freshly-allocated pool
-                # pages — no dense materialization, no write-back at finish
-                T = self.page_tokens
-                slot.table = list(pages)
-                slot.owned_from = len(pages)
-                slot.prefix_nodes = nodes
-                new_pages: list[int] = []
-                try:
-                    for _ in range(len(pages), -(-length // T)):
-                        new_pages.append(self._alloc_decode_page())
-                except RuntimeError:
-                    for page in new_pages:
-                        self.pool.free_device(page)
-                    self.tree.release_nodes(nodes)
-                    self.tree.unpin(pid)
-                    self._free_slots.append(sid)
-                    raise
-                slot.table.extend(new_pages)
-                self.pool.write_device_pages(new_pages, k_suf, v_suf)
             self.lengths[sid] = length
             self.last_token[sid] = first_token
             self._tail_token[sid] = req.tokens[-1]  # prefill wrote its KV last
-            self.slots[sid] = slot
             return sid
 
     # --------------------------------------------------- chunked prefill
@@ -801,8 +745,8 @@ class Engine:
         ``prefill_bucket`` tokens and the page-gathered prefix pads to the
         table bucket (tail masked via ``prefix_valid``), so the jitted
         chunk fn compiles once per (prefix-bucket, chunk-bucket) pair and
-        is shared process-wide — monolithic ``submit`` re-traces per
-        context length instead.
+        is shared process-wide (``submit`` on the paged engine is one
+        such chunk over the whole suffix).
         """
         assert not job.done, "prefill job already completed"
         assert job.remaining > 0, "prefill job was cancelled"
@@ -844,7 +788,6 @@ class Engine:
                 jnp.int32(pos0),
                 jnp.int32(take),
                 jnp.int32(take - 1),                 # final-chunk logit position
-                T,
             )
             logits = out[0]
             self.pool.adopt(*out[1:])
@@ -858,8 +801,7 @@ class Engine:
             return True
 
     def _install_job(self, job: PrefillJob) -> None:
-        """Final chunk landed: install the job's slot for decode (the
-        chunked twin of ``submit``'s step 3)."""
+        """Final chunk landed: install the job's slot for decode."""
         req = job.request
         sid = job.slot_id
         length = len(req.tokens)
@@ -1255,9 +1197,18 @@ class Engine:
         return n
 
     def discard_program(self, pid: str, tier: Tier) -> None:
+        """Free the program's pages on ``tier``, leaves first. A node that
+        a live block table holds, or that another chain hangs from on that
+        tier (a shared prompt), keeps its page, as eviction does: freeing
+        it would leave a hole at which every later match of that chain
+        stops, while the chain's pages below it stay in the tree. The
+        program is released once none of its nodes holds a page that is
+        its own, i.e. a page no other chain hangs from on the page's tier
+        (the test discard itself applies, on either tier, so a page kept
+        on the other tier is still found by a later discard there)."""
         self._san_scope(f"discard_program:{pid}:{tier.value}")
         for node in reversed(self.tree.program_nodes(pid)):
-            if node.refcount > 0:
+            if node.refcount > 0 or not node.is_leaf_on(tier.value):
                 continue
             if tier is Tier.GPU and node.device_page is not None:
                 self.pool.free_device(node.device_page)
@@ -1267,7 +1218,8 @@ class Engine:
                 node.host_page = None
             self.tree._gc(node)
         if not any(
-            n.device_page is not None or n.host_page is not None
+            (n.device_page is not None and n.is_leaf_on(Tier.GPU.value))
+            or (n.host_page is not None and n.is_leaf_on(Tier.CPU.value))
             for n in self.tree.program_nodes(pid)
         ):
             self.tree.release_program(pid)

@@ -1,18 +1,22 @@
 """Two-tier paged KV storage: device pages (HBM) + host pages (DRAM).
 
-Layout ``[L, n_pages, page_tokens, KH, HD]`` for K and V — the trailing
-(page_tokens, head_dim) tile is what the Pallas paged-attention kernel
-consumes per grid step. Host pages are numpy arrays (on a real TPU host:
-pinned DRAM reached via ``jax.device_get/put``; in this CPU container the
-transfer mechanics — block granularity, explicit copies, byte accounting —
-are identical, only the wire is missing).
+Layout ``[L, n_pages, page_tokens, KH*HD]`` for K and V: a page is one
+lane-dense ``[page_tokens, KH*HD]`` tile (a token's heads side by side),
+which the Pallas paged-attention kernel moves in one DMA per grid step,
+and which a TPU holds row-major with no padding. Host pages keep the same
+layout as numpy arrays (on a real TPU host: pinned DRAM reached via
+``jax.device_get/put``; in this CPU container the transfer mechanics —
+block granularity, explicit copies, byte accounting — are identical, only
+the wire is missing). The verbs that take or return tokens speak
+``[L, t, KH, HD]`` and reshape.
 
 Since the block-table decode path landed, the pool **is** the decode
 state: :meth:`block_table_view` hands ``(k, v)`` straight to
-``Model.decode_paged`` / the Pallas ``paged_attention`` kernel, the
-engine's jitted (donated) step appends each new token's KV into the tail
-pages in one batched scatter, and :meth:`adopt` installs the updated
-arrays back. No dense per-slot copy of any page ever exists.
+``Model.decode_paged`` / ``Model.prefill_paged``, whose jitted (donated)
+steps carry it through the layer scan and write each layer's pages in
+place, and :meth:`adopt` installs the updated arrays back. Every other
+write here is donated too, so no write ever leaves a second pool-sized
+buffer on the device, and no dense per-slot copy of any page exists.
 
 **Tier formats.** Each tier declares a page format from
 ``repro.kernels.kv_quant.PAGE_FORMATS``:
@@ -67,71 +71,36 @@ def _filled(shape, dtype, value, sharding):
     )()
 
 
-def scatter_token_run(k_arr, v_arr, page_idx, k_tokens, v_tokens, page_tokens):
-    """Scatter a token run ``[L, S, KH, HD]`` into pool pages in ONE
-    functional update (pure; jit-safe, so the engine's chunked-prefill step
-    can run it under donation for an in-place pool write). ``page_idx``
-    receives consecutive ``page_tokens``-sized chunks; a partial tail is
-    zero-padded. Returns the updated ``(k_arr, v_arr)``."""
-    T = page_tokens
-    L, S, KH, HD = k_tokens.shape
-    n = len(page_idx) if isinstance(page_idx, list) else page_idx.shape[0]
-    pad = n * T - S
-    if pad:
-        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-        k_tokens = jnp.pad(k_tokens, widths)
-        v_tokens = jnp.pad(v_tokens, widths)
-    idx = jnp.asarray(page_idx, jnp.int32)
-    kc = k_tokens.reshape(L, n, T, KH, HD).astype(k_arr.dtype)
-    vc = v_tokens.reshape(L, n, T, KH, HD).astype(v_arr.dtype)
-    return k_arr.at[:, idx].set(kc), v_arr.at[:, idx].set(vc)
+@partial(jax.jit, donate_argnums=(0, 1))
+def _write_tokens(k_arr, v_arr, page, offset, k_tokens, v_tokens):
+    """``arr[:, page, offset:offset + t] = tokens`` (``[L, t, F]``) in
+    place, for K and V (both donated)."""
+    def one(arr, tokens):
+        return jax.lax.dynamic_update_slice(
+            arr, tokens[:, None].astype(arr.dtype), (0, page, offset, 0)
+        )
+
+    return one(k_arr, k_tokens), one(v_arr, v_tokens)
 
 
-def gather_token_run(k_arr, v_arr, page_idx):
-    """Gather pages -> ``[L, n*page_tokens, KH, HD]`` (pure; jit-safe twin
-    of :meth:`PagePool.read_device_pages`)."""
-    idx = jnp.asarray(page_idx, jnp.int32)
-    k = k_arr[:, idx]                                           # [L,n,t,KH,HD]
-    v = v_arr[:, idx]
-    L, n, t, KH, HD = k.shape
-    return k.reshape(L, n * t, KH, HD), v.reshape(L, n * t, KH, HD)
+@partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+def _write_tokens_q(k_arr, k_scale, v_arr, v_scale, page, offset, k_tokens,
+                    v_tokens):
+    """Int8 twin of :func:`_write_tokens`: the page is dequantized, the
+    tokens land at ``offset`` (existing content around them survives), and
+    the page is requantized with a fresh scale (it may grow to admit the
+    new tokens). Payload and sidecars are donated."""
+    def one(arr, scale, tokens):
+        tile = kv_quant.dequantize_pages(arr[:, page], scale[:, page], jnp.float32)
+        tile = jax.lax.dynamic_update_slice(
+            tile, tokens.astype(jnp.float32), (0, offset, 0)
+        )
+        q, s = kv_quant.quantize_pages(tile)
+        return arr.at[:, page].set(q), scale.at[:, page].set(s)
 
-
-def scatter_token_run_q(
-    k_arr, k_scale, v_arr, v_scale, page_idx, k_tokens, v_tokens, page_tokens
-):
-    """Quantizing twin of :func:`scatter_token_run` for an int8-resident
-    pool: the incoming run is split into pages, each page quantized with
-    its own scale, and payload + sidecars land in one scatter apiece.
-    Returns ``(k_arr, k_scale, v_arr, v_scale)`` (pure; jit-safe)."""
-    T = page_tokens
-    L, S, KH, HD = k_tokens.shape
-    n = len(page_idx) if isinstance(page_idx, list) else page_idx.shape[0]
-    pad = n * T - S
-    if pad:
-        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-        k_tokens = jnp.pad(k_tokens, widths)
-        v_tokens = jnp.pad(v_tokens, widths)
-    idx = jnp.asarray(page_idx, jnp.int32)
-    kq, ks = kv_quant.quantize_pages(k_tokens.reshape(L, n, T, KH, HD))
-    vq, vs = kv_quant.quantize_pages(v_tokens.reshape(L, n, T, KH, HD))
-    return (
-        k_arr.at[:, idx].set(kq),
-        k_scale.at[:, idx].set(ks),
-        v_arr.at[:, idx].set(vq),
-        v_scale.at[:, idx].set(vs),
-    )
-
-
-def gather_token_run_q(k_arr, k_scale, v_arr, v_scale, page_idx, dtype):
-    """Dequantizing twin of :func:`gather_token_run`: gathers int8 pages +
-    scale sidecars and returns ``[L, n*page_tokens, KH, HD]`` in the
-    logical ``dtype`` (pure; jit-safe)."""
-    idx = jnp.asarray(page_idx, jnp.int32)
-    k = kv_quant.dequantize_pages(k_arr[:, idx], k_scale[:, idx], dtype)
-    v = kv_quant.dequantize_pages(v_arr[:, idx], v_scale[:, idx], dtype)
-    L, n, t, KH, HD = k.shape
-    return k.reshape(L, n * t, KH, HD), v.reshape(L, n * t, KH, HD)
+    k_arr, k_scale = one(k_arr, k_scale, k_tokens)
+    v_arr, v_scale = one(v_arr, v_scale, v_tokens)
+    return k_arr, k_scale, v_arr, v_scale
 
 
 @dataclass
@@ -175,7 +144,7 @@ class PagePool:
                 "copy could preserve"
             )
         self.quantized_device = self.device_format == "int8"
-        shape = (layers, n_device_pages, page_tokens, kv_heads, head_dim)
+        shape = (layers, n_device_pages, page_tokens, kv_heads * head_dim)
         if self.quantized_device:
             self.k = _filled(shape, jnp.int8, 0, sharding)
             self.v = _filled(shape, jnp.int8, 0, sharding)
@@ -189,7 +158,7 @@ class PagePool:
             self.v = _filled(shape, dtype, 0, sharding)
             self.k_scale = None
             self.v_scale = None
-        hshape = (layers, n_host_pages, page_tokens, kv_heads, head_dim)
+        hshape = (layers, n_host_pages, page_tokens, kv_heads * head_dim)
         # host pages hold either the *raw bits* of the device dtype (bf16 ->
         # uint16 view: an offload→reload round trip must be bit-exact; the
         # old float16 staging was lossy — bf16's exponent range overflows
@@ -281,9 +250,9 @@ class PagePool:
     # -------------------------------------------------------------- writes
     def block_table_view(self):
         """The pool's device arrays ``(k, v)``, each
-        ``[L, n_pages, page_tokens, KH, HD]`` — the operand the block-table
-        decode path (``Model.decode_paged`` -> Pallas ``paged_attention``)
-        consumes directly. This is a zero-copy handle, not a gather: block
+        ``[L, n_pages, page_tokens, KH*HD]`` — the operand the block-table
+        steps (``Model.decode_paged`` -> Pallas ``paged_attention``,
+        ``Model.prefill_paged``) consume directly. This is a zero-copy handle, not a gather: block
         tables index into these arrays page by page. On an int8-resident
         pool the arrays are the quantized payload; :meth:`scale_view`
         hands out the sidecars the kernel dequantizes with."""
@@ -299,9 +268,9 @@ class PagePool:
     def adopt(self, k, v, k_scale=None, v_scale=None) -> None:
         """Install functionally-updated page arrays (same shapes/dtypes).
 
-        The engine's jitted decode step takes :meth:`block_table_view`,
-        appends the new tokens' KV into tail pages, and returns fresh
-        arrays (with donation the update is in-place on the device); this
+        The engine's jitted steps take :meth:`block_table_view`, write
+        the new tokens' KV into their pages, and return fresh arrays (with
+        donation the update is in-place on the device); this
         re-points the pool at them. Page *ids* are stable across adopt —
         only tail-page contents changed — so host copies, free lists and
         in-flight transfer staging stay valid. An int8-resident pool's
@@ -316,81 +285,43 @@ class PagePool:
             assert k_scale.shape == self.k_scale.shape
             self.k_scale, self.v_scale = k_scale, v_scale
 
+    def _flat(self, tokens):
+        """Tokens ``[L, t, KH, HD]`` (or one token, ``[L, KH, HD]``) as
+        page rows ``[L, t, KH*HD]``."""
+        return tokens.reshape(self.layers, -1, self.kv_heads * self.head_dim)
+
+    def _write(self, page: int, offset: int, k_tokens, v_tokens) -> None:
+        """Tokens ``[L, t, KH, HD]`` into ``page`` from ``offset`` on, in
+        place (donated); an int8 page is requantized."""
+        k_tokens, v_tokens = self._flat(k_tokens), self._flat(v_tokens)
+        if self.quantized_device:
+            self.k, self.k_scale, self.v, self.v_scale = _write_tokens_q(
+                self.k, self.k_scale, self.v, self.v_scale, page, offset,
+                k_tokens, v_tokens,
+            )
+            return
+        self.k, self.v = _write_tokens(
+            self.k, self.v, page, offset, k_tokens, v_tokens
+        )
+
     def append_token(self, page: int, offset: int, k_tok, v_tok) -> None:
         """Write one token's KV (``[L, KH, HD]``) into ``page`` at
         ``offset`` — the host-side append-to-tail-page verb. The hot decode
-        path appends *inside* jit (``Model.decode_paged`` commits all
-        layers in one batched scatter); this method serves tests and
+        path appends *inside* jit (``Model.decode_paged`` writes each
+        layer's token into the carried pool); this method serves tests and
         host-driven fixups. On an int8 pool the touched page is
         requantized (its scale may grow to admit the new token)."""
         if self._san is not None:
             self._san.on_append("dev", page, offset)
-        if self.quantized_device:
-            idx = jnp.asarray([page], jnp.int32)
-            off = jnp.asarray([offset], jnp.int32)
-            self.k, self.k_scale = kv_quant.requantize_insert_run(
-                self.k, self.k_scale, idx, off, k_tok[:, None]
-            )
-            self.v, self.v_scale = kv_quant.requantize_insert_run(
-                self.v, self.v_scale, idx, off, v_tok[:, None]
-            )
-            return
-        self.k = self.k.at[:, page, offset].set(k_tok.astype(self.k.dtype))
-        self.v = self.v.at[:, page, offset].set(v_tok.astype(self.v.dtype))
+        self._write(page, offset, k_tok, v_tok)
 
     def write_device_page(self, page: int, k_tokens, v_tokens) -> None:
-        """k_tokens/v_tokens: [L, t<=page_tokens, KH, HD]."""
+        """k_tokens/v_tokens: [L, t<=page_tokens, KH, HD], written from the
+        page's first position; the rest of the page is left as it was."""
         if self._san is not None:
             self._san.on_write("dev", page)
         self._fmt_event("dev", page, self.device_format)
-        t = k_tokens.shape[1]
-        if self.quantized_device:
-            # rebuild the full page in f32 (existing tail content survives a
-            # partial write), then requantize with a fresh per-page scale
-            kf = kv_quant.dequantize_pages(
-                self.k[:, page][:, None], self.k_scale[:, page][:, None],
-                jnp.float32,
-            )[:, 0]
-            vf = kv_quant.dequantize_pages(
-                self.v[:, page][:, None], self.v_scale[:, page][:, None],
-                jnp.float32,
-            )[:, 0]
-            kf = kf.at[:, :t].set(k_tokens.astype(jnp.float32))
-            vf = vf.at[:, :t].set(v_tokens.astype(jnp.float32))
-            kq, ks = kv_quant.quantize_pages(kf)
-            vq, vs = kv_quant.quantize_pages(vf)
-            self.k = self.k.at[:, page].set(kq)
-            self.v = self.v.at[:, page].set(vq)
-            self.k_scale = self.k_scale.at[:, page].set(ks)
-            self.v_scale = self.v_scale.at[:, page].set(vs)
-            return
-        self.k = self.k.at[:, page, :t].set(k_tokens.astype(self.k.dtype))
-        self.v = self.v.at[:, page, :t].set(v_tokens.astype(self.v.dtype))
-
-    def write_device_pages(self, pages: list[int], k_tokens, v_tokens) -> None:
-        """Write a token run spanning several pages in ONE scatter.
-
-        k_tokens/v_tokens: ``[L, S, KH, HD]`` with the run starting at a
-        page boundary; ``pages`` receive consecutive ``page_tokens``-sized
-        chunks (the last may be partial — it is zero-padded). One scatter
-        = one functional pool update, instead of a full-pool copy per page
-        (the prefill-into-pages hot path in ``Engine.submit``).
-        """
-        if not pages:
-            return
-        if self._san is not None:
-            for page in pages:
-                self._san.on_write("dev", page)
-                self._san.on_format("dev", page, self.device_format)
-        if self.quantized_device:
-            self.k, self.k_scale, self.v, self.v_scale = scatter_token_run_q(
-                self.k, self.k_scale, self.v, self.v_scale,
-                pages, k_tokens, v_tokens, self.page_tokens,
-            )
-            return
-        self.k, self.v = scatter_token_run(
-            self.k, self.v, pages, k_tokens, v_tokens, self.page_tokens
-        )
+        self._write(page, 0, k_tokens, v_tokens)
 
     def read_device_pages(self, pages: list[int]):
         """Gather pages -> [L, n*page_tokens, KH, HD] (slot assembly),
@@ -398,11 +329,13 @@ class PagePool:
         if self._san is not None:
             for page in pages:
                 self._san.on_read("dev", page)
+        idx = jnp.asarray(pages, jnp.int32)
+        k, v = self.k[:, idx], self.v[:, idx]                  # [L,n,T,F]
         if self.quantized_device:
-            return gather_token_run_q(
-                self.k, self.k_scale, self.v, self.v_scale, pages, self.dtype
-            )
-        return gather_token_run(self.k, self.v, pages)
+            k = kv_quant.dequantize_pages(k, self.k_scale[:, idx], self.dtype)
+            v = kv_quant.dequantize_pages(v, self.v_scale[:, idx], self.dtype)
+        shape = (self.layers, -1, self.kv_heads, self.head_dim)
+        return k.reshape(shape), v.reshape(shape)
 
     # ----------------------------------------------------------- transfers
     def _encode_host(self, dev_arr) -> np.ndarray:

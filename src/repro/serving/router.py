@@ -34,10 +34,9 @@ while the pump runs one ``prefill_token_budget``-bounded chunk per settle
 visit, and due decode steps interleave between chunks instead of stalling
 behind a whole prefill.
 Chunk shapes are bucketed so the jitted chunk prefill compiles once per
-bucket process-wide — monolithic submit re-traces per context length —
-which is where the measured TTFT win (``RouterMetrics.ttft_s``) comes
-from. Token streams are pinned identical to monolithic submit by
-``tests/test_chunked_prefill.py``.
+bucket process-wide; the measured TTFT win (``RouterMetrics.ttft_s``)
+comes from interleaving. Token streams are pinned identical to a
+whole-suffix submit (one chunk) by ``tests/test_chunked_prefill.py``.
 
 Transfers execute in one of two modes:
 

@@ -34,7 +34,17 @@ PAGED_SHAPES = [
     (3, 8, 2, 64, 8, 4),      # GQA 4:1
     (2, 16, 8, 128, 16, 3),   # GQA 2:1, 128-dim
     (4, 4, 1, 64, 16, 5),     # MQA
+    (2, 16, 16, 64, 16, 3),   # MHA at qwen1.5-0.5b's widths
+    (2, 48, 8, 128, 16, 2),   # GQA 6:1 at internlm2-20b's widths
 ]
+#: every pool below holds two layers, and attention reads the second: the
+#: layer index, not a sliced operand, picks the pages
+LAYERS, LAYER = 2, 1
+
+
+def pool_pages(n_pages, T, KH, D, dtype):
+    """Random pool pages in the serving layout ``[L, N, T, KH*D]``."""
+    return randn((LAYERS, n_pages, T, KH * D), dtype)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -43,14 +53,14 @@ def test_paged_attention_matches_ref(shape, dtype):
     B, H, KH, D, T, P = shape
     n_pages = B * P + 3
     q = randn((B, H, D), dtype)
-    k = randn((n_pages, T, KH, D), dtype)
-    v = randn((n_pages, T, KH, D), dtype)
+    k = pool_pages(n_pages, T, KH, D, dtype)
+    v = pool_pages(n_pages, T, KH, D, dtype)
     tables = jnp.asarray(
         RNG.permutation(n_pages)[: B * P].reshape(B, P), jnp.int32
     )
     lengths = jnp.asarray(RNG.integers(1, P * T + 1, B), jnp.int32)
-    out = paged_attention(q, k, v, tables, lengths, interpret=True)
-    ref = paged_attention_ref(q, k, v, tables, lengths)
+    out = paged_attention(q, k, v, tables, lengths, layer=LAYER, interpret=True)
+    ref = paged_attention_ref(q, k, v, tables, lengths, layer=LAYER)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol(dtype)
     )
@@ -59,12 +69,14 @@ def test_paged_attention_matches_ref(shape, dtype):
 def test_paged_attention_softcap():
     B, H, KH, D, T, P = 2, 8, 4, 64, 8, 3
     q = randn((B, H, D), jnp.float32)
-    k = randn((B * P, T, KH, D), jnp.float32)
-    v = randn((B * P, T, KH, D), jnp.float32)
+    k = pool_pages(B * P, T, KH, D, jnp.float32)
+    v = pool_pages(B * P, T, KH, D, jnp.float32)
     tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray([T * P, T + 3], jnp.int32)
-    out = paged_attention(q, k, v, tables, lengths, softcap=20.0, interpret=True)
-    ref = paged_attention_ref(q, k, v, tables, lengths, softcap=20.0)
+    out = paged_attention(q, k, v, tables, lengths, layer=LAYER, softcap=20.0,
+                          interpret=True)
+    ref = paged_attention_ref(q, k, v, tables, lengths, layer=LAYER,
+                              softcap=20.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
@@ -74,12 +86,14 @@ def test_paged_attention_sliding_window(window):
     than the context (ragged lengths include a partially-filled tail page)."""
     B, H, KH, D, T, P = 3, 8, 4, 64, 8, 4
     q = randn((B, H, D), jnp.float32)
-    k = randn((B * P, T, KH, D), jnp.float32)
-    v = randn((B * P, T, KH, D), jnp.float32)
+    k = pool_pages(B * P, T, KH, D, jnp.float32)
+    v = pool_pages(B * P, T, KH, D, jnp.float32)
     tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray([T * P, 2 * T + 5, 3], jnp.int32)
-    out = paged_attention(q, k, v, tables, lengths, window=window, interpret=True)
-    ref = paged_attention_ref(q, k, v, tables, lengths, window=window)
+    out = paged_attention(q, k, v, tables, lengths, layer=LAYER, window=window,
+                          interpret=True)
+    ref = paged_attention_ref(q, k, v, tables, lengths, layer=LAYER,
+                              window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
@@ -91,13 +105,14 @@ def test_paged_attention_window_matches_decode_attention():
     B, H, KH, D, T, P = 2, 4, 2, 64, 8, 3
     window = 10
     q = randn((B, H, D), jnp.float32)
-    k = randn((B * P, T, KH, D), jnp.float32)
-    v = randn((B * P, T, KH, D), jnp.float32)
+    k = pool_pages(B * P, T, KH, D, jnp.float32)
+    v = pool_pages(B * P, T, KH, D, jnp.float32)
     tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray([T * P - 2, T + 3], jnp.int32)
-    ref = paged_attention_ref(q, k, v, tables, lengths, window=window)
-    k_dense = k[tables].reshape(B, P * T, KH, D)
-    v_dense = v[tables].reshape(B, P * T, KH, D)
+    ref = paged_attention_ref(q, k, v, tables, lengths, layer=LAYER,
+                              window=window)
+    k_dense = k[LAYER, tables].reshape(B, P * T, KH, D)
+    v_dense = v[LAYER, tables].reshape(B, P * T, KH, D)
     dense = decode_attention(
         q, k_dense, v_dense, lengths=lengths, window=window
     ).reshape(B, H, D)
@@ -110,14 +125,16 @@ def test_paged_attention_gqa_softcap_window_combined(dtype):
     sliding window on ragged lengths with partial tail pages."""
     B, H, KH, D, T, P = 2, 8, 2, 64, 16, 3
     q = randn((B, H, D), dtype)
-    k = randn((B * P, T, KH, D), dtype)
-    v = randn((B * P, T, KH, D), dtype)
+    k = pool_pages(B * P, T, KH, D, dtype)
+    v = pool_pages(B * P, T, KH, D, dtype)
     tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray([2 * T + 7, T - 1], jnp.int32)
     out = paged_attention(
-        q, k, v, tables, lengths, softcap=50.0, window=20, interpret=True
+        q, k, v, tables, lengths, layer=LAYER, softcap=50.0, window=20,
+        interpret=True,
     )
-    ref = paged_attention_ref(q, k, v, tables, lengths, softcap=50.0, window=20)
+    ref = paged_attention_ref(q, k, v, tables, lengths, layer=LAYER,
+                              softcap=50.0, window=20)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol(dtype)
     )
@@ -129,15 +146,15 @@ def test_paged_attention_partial_tail_page_isolated():
     there next step)."""
     B, H, KH, D, T, P = 1, 4, 2, 64, 8, 2
     q = randn((B, H, D), jnp.float32)
-    k = randn((B * P, T, KH, D), jnp.float32)
-    v = randn((B * P, T, KH, D), jnp.float32)
+    k = pool_pages(B * P, T, KH, D, jnp.float32)
+    v = pool_pages(B * P, T, KH, D, jnp.float32)
     tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray([T + 5], jnp.int32)  # tail page 5/8 full
-    out1 = paged_attention(q, k, v, tables, lengths, interpret=True)
-    k2 = k.at[1, 5:].set(123.0)  # poison the unwritten tail slots
-    v2 = v.at[1, 5:].set(-123.0)
-    out2 = paged_attention(q, k2, v2, tables, lengths, interpret=True)
-    ref2 = paged_attention_ref(q, k2, v2, tables, lengths)
+    out1 = paged_attention(q, k, v, tables, lengths, layer=LAYER, interpret=True)
+    k2 = k.at[LAYER, 1, 5:].set(123.0)  # poison the unwritten tail slots
+    v2 = v.at[LAYER, 1, 5:].set(-123.0)
+    out2 = paged_attention(q, k2, v2, tables, lengths, layer=LAYER, interpret=True)
+    ref2 = paged_attention_ref(q, k2, v2, tables, lengths, layer=LAYER)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2), rtol=2e-3, atol=2e-3)
 
@@ -146,14 +163,14 @@ def test_paged_attention_ignores_garbage_beyond_length():
     """Pages past `lengths` must not affect the result (MORI evicts them)."""
     B, H, KH, D, T, P = 1, 4, 2, 64, 8, 3
     q = randn((B, H, D), jnp.float32)
-    k = randn((B * P, T, KH, D), jnp.float32)
-    v = randn((B * P, T, KH, D), jnp.float32)
+    k = pool_pages(B * P, T, KH, D, jnp.float32)
+    v = pool_pages(B * P, T, KH, D, jnp.float32)
     tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray([T + 2], jnp.int32)
-    out1 = paged_attention(q, k, v, tables, lengths, interpret=True)
-    k2 = k.at[2].set(1e4)  # poison the unused page
-    v2 = v.at[2].set(-1e4)
-    out2 = paged_attention(q, k2, v2, tables, lengths, interpret=True)
+    out1 = paged_attention(q, k, v, tables, lengths, layer=LAYER, interpret=True)
+    k2 = k.at[LAYER, 2].set(1e4)  # poison the unused page
+    v2 = v.at[LAYER, 2].set(-1e4)
+    out2 = paged_attention(q, k2, v2, tables, lengths, layer=LAYER, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
 
 
@@ -259,14 +276,16 @@ def test_property_paged_attention_equals_ref(seed, kh, g, pages):
     H = kh * g
     n_pages = B * pages + 1
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((n_pages, T, kh, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((n_pages, T, kh, D)), jnp.float32)
+    shape = (LAYERS, n_pages, T, kh * D)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     tables = jnp.asarray(
         rng.permutation(n_pages)[: B * pages].reshape(B, pages), jnp.int32
     )
     lengths = jnp.asarray(rng.integers(1, pages * T + 1, B), jnp.int32)
-    out = paged_attention(q, k, v, tables, lengths, interpret=True)
-    ref = paged_attention_ref(q, k, v, tables, lengths)
+    layer = int(rng.integers(LAYERS))
+    out = paged_attention(q, k, v, tables, lengths, layer=layer, interpret=True)
+    ref = paged_attention_ref(q, k, v, tables, lengths, layer=layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-3, atol=5e-3)
 
 

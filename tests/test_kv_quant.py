@@ -32,12 +32,17 @@ def randn(shape, dtype=jnp.float32):
     return jnp.asarray(RNG.standard_normal(shape), dtype)
 
 
+#: the pools below hold two layers in the serving layout ``[L, N, T,
+#: KH*D]``; attention reads the second
+LAYERS, LAYER = 2, 1
+
+
 def quantized_case(B, H, KH, D, T, P, lengths):
     """Build bf16-ish pages + their int8 twins for one attention case."""
     n_pages = B * P
     q = randn((B, H, D))
-    k = randn((n_pages, T, KH, D))
-    v = randn((n_pages, T, KH, D))
+    k = randn((LAYERS, n_pages, T, KH * D))
+    v = randn((LAYERS, n_pages, T, KH * D))
     tables = jnp.arange(n_pages, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.asarray(lengths, jnp.int32)
     kq, ks = kv_quant.quantize_pages(k)
@@ -48,39 +53,42 @@ def quantized_case(B, H, KH, D, T, P, lengths):
 # ===================================================== transform round trips
 class TestQuantTransforms:
     def test_quantize_dequantize_error_bound(self):
-        x = randn((6, 8, 2, 16))
+        x = randn((6, 8, 32))
         q, s = kv_quant.quantize_pages(x)
         back = kv_quant.dequantize_pages(q, s, jnp.float32)
         # per-element bound: half a quantization step of that page's scale
-        bound = np.asarray(s)[:, None, None, None] * 0.5 + 1e-7
+        bound = np.asarray(s)[:, None, None] * 0.5 + 1e-7
         assert (np.abs(np.asarray(back) - np.asarray(x)) <= bound).all()
 
     def test_jnp_and_np_quantizers_agree_bitwise(self):
         """Device- and host-side quantization of the same page must produce
         identical bytes, or staged copies would differ by path taken."""
-        x = RNG.standard_normal((4, 8, 2, 16)).astype(np.float32)
+        x = RNG.standard_normal((4, 8, 32)).astype(np.float32)
         qj, sj = kv_quant.quantize_pages(jnp.asarray(x))
         qn, sn = kv_quant.quantize_np(x)
         np.testing.assert_array_equal(np.asarray(qj), qn)
         np.testing.assert_array_equal(np.asarray(sj), sn)
 
     def test_all_zero_page_is_representable(self):
-        q, s = kv_quant.quantize_pages(jnp.zeros((2, 8, 2, 16)))
+        q, s = kv_quant.quantize_pages(jnp.zeros((2, 8, 32)))
         assert np.asarray(s).min() > 0          # SCALE_EPS floor, finite math
         assert (np.asarray(q) == 0).all()
 
     def test_requantize_insert_grows_scale(self):
         """Appending a token larger than the page's amax must widen the
         scale — the old scale would clip it."""
-        x = randn((1, 8, 2, 16)) * 0.1
+        x = randn((LAYERS, 1, 8, 32)) * 0.1
         q, s = kv_quant.quantize_pages(x)
-        big = jnp.full((1, 2, 16), 7.0, jnp.float32)
+        big = jnp.full((1, 32), 7.0, jnp.float32)
         q2, s2 = kv_quant.requantize_insert(
-            q, s, jnp.asarray([0], jnp.int32), jnp.asarray([3], jnp.int32), big
+            q, s, LAYER, jnp.asarray([0], jnp.int32),
+            jnp.asarray([3], jnp.int32), big,
         )
-        assert float(s2[0]) > float(s[0])
+        assert float(s2[LAYER, 0]) > float(s[LAYER, 0])
         back = kv_quant.dequantize_pages(q2, s2, jnp.float32)
-        np.testing.assert_allclose(np.asarray(back[0, 3]), 7.0, rtol=1e-2)
+        np.testing.assert_allclose(np.asarray(back[LAYER, 0, 3]), 7.0, rtol=1e-2)
+        # the other layer's page is untouched
+        np.testing.assert_array_equal(np.asarray(q2[0]), np.asarray(q[0]))
 
     def test_wire_bytes_halve_plus_sidecar(self):
         L, T, KH, HD = 4, 8, 2, 16
@@ -113,6 +121,10 @@ class TestInt8KernelParity:
                              lengths=[13], softcap=None, window=None),
         "all-at-once": dict(B=2, H=8, KH=2, D=64, T=16, P=3,
                             lengths=[39, 15], softcap=50.0, window=20),
+        "qwen-mha": dict(B=2, H=16, KH=16, D=64, T=16, P=3,
+                         lengths=[41, 16], softcap=None, window=None),
+        "internlm2-gqa": dict(B=2, H=48, KH=8, D=128, T=16, P=2,
+                              lengths=[32, 9], softcap=None, window=None),
     }
 
     @pytest.mark.parametrize("name", sorted(CASES))
@@ -122,11 +134,11 @@ class TestInt8KernelParity:
             c["B"], c["H"], c["KH"], c["D"], c["T"], c["P"], c["lengths"]
         )
         out = paged_attention(
-            q, kq, vq, tables, lengths, ks, vs,
+            q, kq, vq, tables, lengths, ks, vs, layer=LAYER,
             softcap=c["softcap"], window=c["window"], interpret=True,
         )
         ref = paged_attention_ref(
-            q, kq, vq, tables, lengths, ks, vs,
+            q, kq, vq, tables, lengths, ks, vs, layer=LAYER,
             softcap=c["softcap"], window=c["window"],
         )
         err = float(np.max(np.abs(np.asarray(out) - np.asarray(ref))))
@@ -139,32 +151,70 @@ class TestInt8KernelParity:
             c["B"], c["H"], c["KH"], c["D"], c["T"], c["P"], c["lengths"]
         )
         out = paged_attention(
-            q, kq, vq, tables, lengths, ks, vs,
+            q, kq, vq, tables, lengths, ks, vs, layer=LAYER,
             softcap=c["softcap"], window=c["window"], interpret=True,
         )
         oracle = paged_attention_ref(
-            q, k, v, tables, lengths,
+            q, k, v, tables, lengths, layer=LAYER,
             softcap=c["softcap"], window=c["window"],
         )
         err = float(np.max(np.abs(np.asarray(out) - np.asarray(oracle))))
         assert err < QUANT_BAND, f"{name}: quantization error {err:.2e}"
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_decode_requantizes_tail_before_attending(self, name):
+        """The decode op on an int8 pool (the CPU oracle path) writes the
+        new token by requantize-insert and then attends over the written
+        pool: it returns exactly that pool and exactly the attention over
+        it, so the new token is seen at int8 precision, as the kernel
+        sees it."""
+        from repro.kernels.paged_attention.ops import paged_attention_decode
+
+        c = self.CASES[name]
+        q, _, _, kq, ks, vq, vs, tables, lengths = quantized_case(
+            c["B"], c["H"], c["KH"], c["D"], c["T"], c["P"], c["lengths"]
+        )
+        B, T = c["B"], c["T"]
+        k_new, v_new = randn((B, c["KH"], c["D"])), randn((B, c["KH"], c["D"]))
+        pos = np.asarray(lengths) - 1
+        tail_pages = jnp.asarray(np.asarray(tables)[np.arange(B), pos // T])
+        tail_offsets = jnp.asarray(pos % T, jnp.int32)
+        out, kp, vp, ks2, vs2 = paged_attention_decode(
+            q, k_new, v_new, kq, vq, tables, lengths, tail_pages,
+            tail_offsets, ks, vs, layer=LAYER, softcap=c["softcap"],
+            window=c["window"],
+        )
+        want_k, want_ks = kv_quant.requantize_insert(
+            kq, ks, LAYER, tail_pages, tail_offsets, k_new.reshape(B, -1)
+        )
+        want_v, want_vs = kv_quant.requantize_insert(
+            vq, vs, LAYER, tail_pages, tail_offsets, v_new.reshape(B, -1)
+        )
+        for got, want in ((kp, want_k), (vp, want_v), (ks2, want_ks),
+                          (vs2, want_vs)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        ref = paged_attention_ref(
+            q, want_k, want_v, tables, lengths, want_ks, want_vs,
+            layer=LAYER, softcap=c["softcap"], window=c["window"],
+        )
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     def test_partial_tail_garbage_isolated_under_int8(self):
         """Tokens past ``lengths`` in a quantized tail page must not leak
         into the output — even though they share the page's scale."""
         B, H, KH, D, T, P = 1, 4, 2, 64, 8, 2
         q = randn((B, H, D))
-        k = randn((B * P, T, KH, D))
-        v = randn((B * P, T, KH, D))
+        k = randn((LAYERS, B * P, T, KH * D))
+        v = randn((LAYERS, B * P, T, KH * D))
         tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
         lengths = jnp.asarray([T + 5], jnp.int32)
-        poisoned_k = k.at[1, 5:].set(123.0)
-        poisoned_v = v.at[1, 5:].set(-123.0)
+        poisoned_k = k.at[LAYER, 1, 5:].set(123.0)
+        poisoned_v = v.at[LAYER, 1, 5:].set(-123.0)
         kq, ks = kv_quant.quantize_pages(poisoned_k)
         vq, vs = kv_quant.quantize_pages(poisoned_v)
-        out = paged_attention(q, kq, vq, tables, lengths, ks, vs,
+        out = paged_attention(q, kq, vq, tables, lengths, ks, vs, layer=LAYER,
                               interpret=True)
-        oracle = paged_attention_ref(q, k, v, tables, lengths)
+        oracle = paged_attention_ref(q, k, v, tables, lengths, layer=LAYER)
         # note the WIDE scale the poison forces on the tail page (amax 123):
         # live tokens quantize coarsely, so only the band is guaranteed
         err = float(np.max(np.abs(np.asarray(out) - np.asarray(oracle))))
@@ -194,8 +244,8 @@ class TestPoolRoundTrips:
         hp = pool.offload_page(page)
         dp = pool.reload_page(hp)
         after_k = np.asarray(pool.k[:, dp], np.float32)
-        scales = np.max(np.abs(before_k), axis=(1, 2, 3)) / kv_quant.QMAX
-        bound = scales[:, None, None, None] * 0.5 + 0.01  # + bf16 rounding
+        scales = np.max(np.abs(before_k), axis=(1, 2)) / kv_quant.QMAX
+        bound = scales[:, None, None] * 0.5 + 0.01  # + bf16 rounding
         assert (np.abs(after_k - before_k) <= bound).all()
 
     def test_scale_sidecars_survive_import_byte_identically(self):

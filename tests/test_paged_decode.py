@@ -299,13 +299,16 @@ class TestOpsInterpretDispatch:
         rng = np.random.default_rng(3)
         B, H, KH, D, T, P = 2, 4, 2, 64, 8, 3
         q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((B * P, T, KH, D)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((B * P, T, KH, D)), jnp.float32)
+        pool = (2, B * P, T, KH * D)                  # [L, N, T, KH*D]
+        k = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+        v = jnp.asarray(rng.standard_normal(pool), jnp.float32)
         tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
         lengths = jnp.asarray([T * P, T + 3], jnp.int32)
         monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-        out = ops.paged_attention(q, k, v, tables, lengths, softcap=30.0, window=10)
-        ref = paged_attention_ref(q, k, v, tables, lengths, softcap=30.0, window=10)
+        out = ops.paged_attention(q, k, v, tables, lengths, layer=1,
+                                  softcap=30.0, window=10)
+        ref = paged_attention_ref(q, k, v, tables, lengths, layer=1,
+                                  softcap=30.0, window=10)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
     def test_flash_attention_ops_interpret(self, monkeypatch):

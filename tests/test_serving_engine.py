@@ -144,6 +144,30 @@ class TestTypedEvictionUnderPressure:
         assert eng.pool.device_free_count() > before
         assert eng.tree.program_nodes("p") == []
 
+    @pytest.mark.parametrize("order", [(Tier.GPU, Tier.CPU), (Tier.CPU, Tier.GPU)])
+    def test_discard_keeps_a_shared_prefix(self, setup, order):
+        """Discarding one program, on both tiers in either order, must not
+        free the pages of a prompt that another program's cached chain
+        hangs from: the other program's next turn matches its whole
+        history and finishes, and the discarded program is released."""
+        cfg, _, params = setup
+        eng = make_engine(cfg, params)
+        prompt = list(range(2, 18))                      # two shared pages
+        ctx = {"a": prompt + list(range(100, 120)),
+               "b": prompt + list(range(200, 220))}
+        for pid in ctx:
+            eng.submit(EngineRequest(pid, ctx[pid], max_new_tokens=3))
+            out = eng.run_to_completion()[0].output_tokens
+            ctx[pid] += out[:-1]
+        for tier in order:
+            eng.discard_program("a", tier)
+        assert eng.tree.program_nodes("a") == []
+        shared = eng.tree.match_prefix(prompt)
+        assert len(shared) == 2 and all(n.device_page is not None for n in shared)
+        eng.submit(EngineRequest("b", ctx["b"] + [7, 8, 9], max_new_tokens=3))
+        done = eng.run_to_completion()[0]
+        assert done.cached_tokens == len(ctx["b"]) // 8 * 8
+
 
 class TestRouterIntegration:
     def test_replay_with_mori(self, setup):
